@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = float("-inf")
 LANES = 128
 
@@ -56,17 +54,23 @@ def _decode_kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
         k = k_ref[0, 0].astype(jnp.float32)          # (blk_k, D)
         v = v_ref[0, 0].astype(jnp.float32)          # (blk_k, D)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (blk_k, 1), 0)
-        valid = k_pos < length
-        valid = jnp.logical_and(
-            valid, jnp.where(win > 0, k_pos >= length - win, True))
-        k = jnp.where(valid, k, 0.0)
-        v = jnp.where(valid, v, 0.0)
+        def valid(k_pos):
+            return jnp.logical_and(
+                k_pos < length,
+                jnp.logical_or(win <= 0, k_pos >= length - win))
+
+        # The mask is built twice, as a column for the (blk_k, D) k/v
+        # blocks and as a row for the (G, blk_k) scores: Mosaic has no
+        # transpose of boolean vectors.
+        col = valid(k_start + jax.lax.broadcasted_iota(jnp.int32, (blk_k, 1), 0))
+        row = valid(k_start + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1))
+        k = jnp.where(col, k, 0.0)
+        v = jnp.where(col, v, 0.0)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                             # (G, blk_k)
-        s = jnp.where(valid.T, s, NEG_INF)
+        s = jnp.where(row, s, NEG_INF)
 
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
@@ -130,7 +134,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, LANES), jnp.float32),
             pltpu.VMEM((G, LANES), jnp.float32),
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, win, qg, k, v)

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = float("-inf")
 LANES = 128
 
@@ -82,7 +80,7 @@ def _attn_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         if causal:
             mask = jnp.logical_and(mask, k_pos <= q_pos)
         mask = jnp.logical_and(
-            mask, jnp.where(win > 0, k_pos > q_pos - win, True))
+            mask, jnp.logical_or(win <= 0, k_pos > q_pos - win))
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:, :1]                               # (blk_q, 1)
@@ -154,7 +152,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((blk_q, LANES), jnp.float32),   # running max
             pltpu.VMEM((blk_q, LANES), jnp.float32),   # running sum
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -18,7 +18,8 @@ unconditional fp32 safety. Chunk -> chunk carries only S in VMEM scratch
 across the sequential grid axis, exactly like the SSD kernel.
 
 Per grid step:  A @ v, (r * exp(L_excl)) @ S, and the rank-L state update
-(k * exp(L_last - L))^T @ v — three MXU contractions per chunk.
+(k * exp(L_last - L))^T @ v — three MXU contractions per chunk. The
+wrapper computes log w and its in-chunk cumsums (L, L_excl) with XLA.
 """
 from __future__ import annotations
 
@@ -29,10 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
-
-def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
+def _rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, lwx_ref, u_ref, s0_ref,
                   o_ref, sout_ref, state_ref, *, chunk: int):
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
@@ -44,34 +43,30 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     r = r_ref[0, 0].astype(jnp.float32)              # (L, D)
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
-    w = w_ref[0, 0].astype(jnp.float32)              # decays in (0, 1)
-    u = u_ref[0].astype(jnp.float32)                 # (D,)
-
-    logw = jnp.log(w)                                # <= 0
-    lw = jnp.cumsum(logw, axis=0)                    # inclusive  (L, D)
-    lwx = lw - logw                                  # exclusive: L_{t-1}
+    lw = lw_ref[0, 0]                                # inclusive  (L, D)
+    lwx = lwx_ref[0, 0]                              # exclusive: L_{t-1}
+    u = u_ref[0].astype(jnp.float32)                 # (1, D)
 
     # pairwise intra-chunk attention with per-channel decay
     dec = jnp.exp(lwx[:, None, :] - lw[None, :, :])  # (L, L, D); tril <= 1
-    a = jnp.einsum("td,jd,tjd->tj", r, k, dec)       # strict lower + diag junk
+    a = jnp.sum(r[:, None, :] * k[None, :, :] * dec, axis=-1)  # (L, L)
     t_idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
     j_idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-    a = jnp.where(t_idx > j_idx, a, 0.0)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)      # bonus term at j == t
-    a = a + jnp.diag(diag)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)  # bonus term at j == t
+    a = jnp.where(t_idx > j_idx, a, 0.0) + jnp.where(t_idx == j_idx, diag, 0.0)
 
     o_intra = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    state = state_ref[...]                           # (Dk, Dv) pre-chunk
-    o_state = jax.lax.dot_general(r * jnp.exp(lwx), state,
-                                  (((1,), (0,)), ((), ())),
+    state_t = state_ref[...]                         # (Dv, Dk) pre-chunk
+    o_state = jax.lax.dot_general(r * jnp.exp(lwx), state_t,
+                                  (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     o_ref[0, 0, ...] = (o_intra + o_state).astype(o_ref.dtype)
 
-    last = lw[-1]                                    # (D,)
-    kd = k * jnp.exp(last[None, :] - lw)             # (L, D), factors <= 1
-    state_ref[...] = jnp.exp(last)[:, None] * state + jax.lax.dot_general(
-        kd, v, (((0,), (0,)), ((), ())),
+    last = lw[chunk - 1:, :]                         # (1, D)
+    kd = k * jnp.exp(last - lw)                      # (L, D), factors <= 1
+    state_ref[...] = jnp.exp(last) * state_t + jax.lax.dot_general(
+        v, kd, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(ci == nc - 1)
@@ -92,20 +87,29 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     nc = S // L
     if s0 is None:
         s0 = jnp.zeros((B, H, D, D), jnp.float32)
+    # Per-chunk log-decay integrals come in precomputed: Mosaic has no
+    # cumsum. The state is carried transposed, (Dv, Dk), so that the
+    # per-key decay scales lanes and no vector is transposed in-kernel.
+    logw = jnp.log(w.astype(jnp.float32))            # <= 0
+    lw = jnp.cumsum(logw.reshape(B, H, nc, L, D), axis=3).reshape(B, H, S, D)
+    lwx = lw - logw
 
     kernel = functools.partial(_rwkv6_kernel, chunk=L)
     blk = pl.BlockSpec((1, 1, L, D), lambda b, h, c: (b, h, c, 0))
     sblk = pl.BlockSpec((1, 1, D, D), lambda b, h, c: (b, h, 0, 0))
-    return pl.pallas_call(
+    o, s_t = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
-        in_specs=[blk, blk, blk, blk,
-                  pl.BlockSpec((1, D), lambda b, h, c: (h, 0)), sblk],
+        in_specs=[blk, blk, blk, blk, blk,
+                  # u as (H, 1, D): a (1, D) block of (H, D) would break
+                  # the TPU's (8, 128) tiling of the last two dimensions
+                  pl.BlockSpec((1, 1, D), lambda b, h, c: (h, 0, 0)), sblk],
         out_specs=(blk, sblk),
         out_shape=(jax.ShapeDtypeStruct((B, H, S, D), jnp.float32),
                    jax.ShapeDtypeStruct((B, H, D, D), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, lw, lwx, u[:, None, :], s0.swapaxes(-1, -2))
+    return o, s_t.swapaxes(-1, -2)

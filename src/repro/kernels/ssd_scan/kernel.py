@@ -8,7 +8,8 @@ VMEM scratch, so the HLO has ONE chunk body regardless of sequence length
 and state never round-trips to HBM — the GPU version's inter-SM state
 handoff becomes a scratch register file, which is the correct analogue.
 
-Per grid step, fp32:
+Per grid step, fp32 (``cum``, the in-chunk cumsum of dA, comes in
+precomputed by the wrapper):
     cum   = cumsum(dA)                         (Q,)    decay integrals
     dec   = tril(exp(cum_i - cum_j))           (Q, Q)
     att   = (C B^T) * dec                      (Q, Q)  MXU
@@ -31,10 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
-
-def _ssd_kernel(xdt_ref, b_ref, c_ref, da_ref, y_ref, state_ref, *, chunk: int):
+def _ssd_kernel(xdt_ref, b_ref, c_ref, cumc_ref, cumr_ref, y_ref, state_ref,
+                *, chunk: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -44,10 +44,10 @@ def _ssd_kernel(xdt_ref, b_ref, c_ref, da_ref, y_ref, state_ref, *, chunk: int):
     xdt = xdt_ref[0, 0].astype(jnp.float32)          # (Q, P)
     B = b_ref[0].astype(jnp.float32)                 # (Q, N)
     C = c_ref[0].astype(jnp.float32)                 # (Q, N)
-    dA = da_ref[0, 0].astype(jnp.float32)            # (Q,)
+    cum = cumc_ref[0, 0]                             # (Q, 1) decay integrals
+    cum_row = cumr_ref[0, 0]                         # (1, Q) the same, as a row
 
-    cum = jnp.cumsum(dA)                             # (Q,)
-    logdec = cum[:, None] - cum[None, :]             # (Q, Q), tril <= 0
+    logdec = cum - cum_row                           # (Q, Q), tril <= 0
     tri = jax.lax.broadcasted_iota(jnp.int32, logdec.shape, 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, logdec.shape, 1)
     dec = jnp.where(tri, jnp.exp(logdec), 0.0)
@@ -59,16 +59,19 @@ def _ssd_kernel(xdt_ref, b_ref, c_ref, da_ref, y_ref, state_ref, *, chunk: int):
                                   preferred_element_type=jnp.float32)
 
     state = state_ref[...]                           # (N, P) pre-chunk
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cum) * jax.lax.dot_general(
         C, state, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (Q, P)
 
     y_ref[0, 0, ...] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    last = cum[-1]
-    sdec = jnp.exp(last - cum)                       # (Q,) <= 1
-    state_ref[...] = jnp.exp(last) * state + jax.lax.dot_general(
-        B, sdec[:, None] * xdt, (((0,), (0,)), ((), ())),
+    last = cum_row[:, chunk - 1:]                    # (1, 1)
+    sdec = jnp.exp(last - cum)                       # (Q, 1) <= 1
+    # widen along lanes first: Mosaic cannot broadcast (1, 1) to (N, P)
+    # in one step
+    decay = jnp.exp(jnp.broadcast_to(last, (1, state.shape[1])))
+    state_ref[...] = decay * state + jax.lax.dot_general(
+        B, sdec * xdt, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (N, P)
 
 
@@ -85,6 +88,12 @@ def ssd_scan(xdt: jax.Array, Bc: jax.Array, Cc: jax.Array, dA: jax.Array, *,
     Q = min(chunk, S)
     assert S % Q == 0, f"seq {S} not divisible by chunk {Q}"
     nc = S // Q
+    # Per-chunk decay integrals, once here rather than a cumsum inside the
+    # kernel; handed over as a column and as a row, because a (1, 1, Q)
+    # block of (B, H, S) breaks the TPU's (8, 128) tiling of the last two
+    # block dimensions.
+    cum = jnp.cumsum(dA.astype(jnp.float32).reshape(B, H, nc, Q),
+                     axis=-1).reshape(B, H, S)
 
     kernel = functools.partial(_ssd_kernel, chunk=Q)
     return pl.pallas_call(
@@ -94,12 +103,13 @@ def ssd_scan(xdt: jax.Array, Bc: jax.Array, Cc: jax.Array, dA: jax.Array, *,
             pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
-            pl.BlockSpec((1, 1, Q), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, 1, Q, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, P), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xdt, Bc, Cc, dA)
+    )(xdt, Bc, Cc, cum[..., None], cum[:, :, None, :])

@@ -16,24 +16,32 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig
+
+
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: the model's ``shard_act``
+    constraints and the GSPMD param shardings assume automatic axes, and
+    ``jax.make_mesh`` defaults to ``Explicit`` ones."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(cfg: MeshConfig) -> jax.sharding.Mesh:
     """Arbitrary mesh from a MeshConfig (elastic sizes, tests)."""
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+    return _auto_mesh(cfg.shape, cfg.axis_names)
 
 
 def single_device_mesh() -> jax.sharding.Mesh:
     """A (1, 1) mesh over the one real device (smoke tests under a mesh)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def survivor_mesh(n_pods_alive: int, *, data: int = 16, model: int = 16
@@ -47,5 +55,5 @@ def survivor_mesh(n_pods_alive: int, *, data: int = 16, model: int = 16
     if n_pods_alive < 1:
         raise ValueError("no pods alive")
     if n_pods_alive == 1:
-        return jax.make_mesh((data, model), ("data", "model"))
-    return jax.make_mesh((n_pods_alive, data, model), ("pod", "data", "model"))
+        return _auto_mesh((data, model), ("data", "model"))
+    return _auto_mesh((n_pods_alive, data, model), ("pod", "data", "model"))

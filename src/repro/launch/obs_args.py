@@ -6,9 +6,9 @@ Both drivers (``launch.train``, ``launch.serve``) expose the same pair:
                     (``repro.obs`` Recorder format; feed it to
                     ``python -m repro.obs.export`` for a Perfetto trace).
 ``--profile DIR``   additionally start a ``jax.profiler`` device trace
-                    into DIR (graceful no-op on backends without profiler
-                    support) and drop ``events.jsonl`` + a validated
-                    ``timeline.trace.json`` next to it, so the device
+                    into DIR (a profiler that cannot start is an error,
+                    not a silent untraced run) and drop ``events.jsonl``
+                    + a validated ``timeline.trace.json`` next to it, so the device
                     trace and the sim/step timeline can be opened
                     side-by-side in Perfetto.
 
@@ -18,10 +18,11 @@ call site sees the NULL recorder and the run is observability-free.
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
+
+import jax
 
 from repro import obs
-from repro.obs import profiling
 
 
 def add_obs_args(ap) -> None:
@@ -29,24 +30,23 @@ def add_obs_args(ap) -> None:
                     help="write a structured event log (JSONL) here")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="jax.profiler trace dir; also writes events.jsonl "
-                         "+ timeline.trace.json (no-op if unsupported)")
+                         "+ timeline.trace.json")
 
 
 def recorder_from_args(args, *, meta: Optional[Dict[str, Any]] = None
-                       ) -> Tuple[Optional[obs.Recorder], bool]:
-    """(recorder, device_trace_started) per the flags; (None, False) when
-    observability is off."""
+                       ) -> Optional[obs.Recorder]:
+    """The recorder the flags ask for, with the device trace started under
+    ``--profile``; None when observability is off."""
     if not (args.events or args.profile):
-        return None, False
+        return None
     rec = obs.Recorder(jsonl=args.events, meta=meta)
-    traced = False
     if args.profile:
         os.makedirs(args.profile, exist_ok=True)
-        traced = profiling.start_trace(args.profile)
-    return rec, traced
+        jax.profiler.start_trace(args.profile)
+    return rec
 
 
-def finalize_recorder(args, rec: Optional[obs.Recorder], traced: bool, *,
+def finalize_recorder(args, rec: Optional[obs.Recorder], *,
                       clock: str = "sim") -> Dict[str, str]:
     """Stop the device trace, flush the log, export the timeline.
 
@@ -57,8 +57,8 @@ def finalize_recorder(args, rec: Optional[obs.Recorder], traced: bool, *,
     from repro.obs import export
 
     out: Dict[str, str] = {}
-    if traced:
-        profiling.stop_trace()
+    if args.profile:
+        jax.profiler.stop_trace()
         out["profile_dir"] = args.profile
     if rec is None:
         return out

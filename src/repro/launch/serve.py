@@ -29,15 +29,16 @@ import argparse
 import json
 import math
 import time
+from typing import Tuple
 
 import jax
 import numpy as np
 
 from repro.config import get_config, list_archs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.obs_args import (add_obs_args, finalize_recorder,
                                    recorder_from_args)
-from repro.models import layers as L
-from repro.models.builder import build_model
+from repro.models.builder import Model, build_model
 from repro.obs.slo import SLOMonitor, SLOSpec
 from repro.obs.timeseries import TimeSeriesSampler, attach_serve_cluster
 from repro.serving import FIFOQueue, Request, ServeEngine, SLOQueue
@@ -189,10 +190,13 @@ def _replay_trace_cluster(args, cluster: ServeCluster, trace: RequestTrace,
     return reqs
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="starcoder2-3b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="published widths (weights held as "
+                         "Model.init_for_serving holds them)")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
@@ -266,17 +270,37 @@ def main() -> None:
                     help="render the HTML ops report (time-series + "
                          "alerts + per-replica summary) here")
     add_obs_args(ap)
-    args = ap.parse_args()
+    return ap
 
+
+def load_model(args) -> Tuple[Model, dict]:
+    """The model ``--arch``/``--full`` name, with random weights from
+    ``--seed`` held as :meth:`Model.init_for_serving` holds them."""
     cfg = get_config(args.arch, reduced=args.reduced)
     if cfg.family == "encdec":
         raise SystemExit("serve driver targets decoder-only families; "
                          "seamless decode is exercised by the dry-run")
     model = build_model(cfg)
-    params = L.unbox(model.init(jax.random.key(args.seed)))
+    return model, model.init_for_serving(jax.random.key(args.seed))
+
+
+def engine_kwargs(args, *, recorder=None, clock=None) -> dict:
+    """``ServeEngine`` keyword arguments from the parsed flags."""
+    return dict(max_batch=args.max_batch, max_len=args.max_len,
+                recorder=recorder, prefill=args.prefill_mode,
+                prefill_block=args.prefill_block,
+                cache_impl=args.cache_impl, page_size=args.page_size,
+                num_pages=args.num_pages, clock=clock)
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    use_compile_cache()
+    model, params = load_model(args)
+    cfg = model.cfg
 
     rng = np.random.default_rng(args.seed)
-    rec, traced = recorder_from_args(
+    rec = recorder_from_args(
         args, meta={"driver": "serve", "arch": args.arch,
                     "trace": args.trace, "queue": args.queue,
                     "prefill": args.prefill_mode,
@@ -290,12 +314,7 @@ def main() -> None:
         return SLOQueue(capacity=args.queue_capacity) \
             if args.queue == "slo" else FIFOQueue()
 
-    engine_kwargs = dict(max_batch=args.max_batch, max_len=args.max_len,
-                         recorder=rec, prefill=args.prefill_mode,
-                         prefill_block=args.prefill_block,
-                         cache_impl=args.cache_impl,
-                         page_size=args.page_size,
-                         num_pages=args.num_pages, clock=engine_clock)
+    kwargs = engine_kwargs(args, recorder=rec, clock=engine_clock)
 
     monitor = sampler = scaler = cluster = None
     if args.monitor:
@@ -316,7 +335,7 @@ def main() -> None:
 
         def make_engine():
             eng = ServeEngine(model, params, queue=make_queue(),
-                              shared_fns=shared.get("fns"), **engine_kwargs)
+                              shared_fns=shared.get("fns"), **kwargs)
             shared.setdefault("fns", eng.shared_fns)
             return eng
 
@@ -347,8 +366,7 @@ def main() -> None:
                 if dec.n_replicas != live:
                     cluster.scale_to(dec.n_replicas)
     else:
-        engine = ServeEngine(model, params, queue=make_queue(),
-                             **engine_kwargs)
+        engine = ServeEngine(model, params, queue=make_queue(), **kwargs)
 
     t0 = time.monotonic()
     if args.trace:
@@ -426,7 +444,7 @@ def main() -> None:
         out["report"] = args.report
     # trace replays live on the virtual clock -> sim timeline; ad-hoc
     # runs keep the host-clock axis
-    out.update(finalize_recorder(args, rec, traced,
+    out.update(finalize_recorder(args, rec,
                                  clock="sim" if args.trace else "wall"))
     print(json.dumps(out, indent=1))
 

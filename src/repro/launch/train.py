@@ -26,6 +26,7 @@ from repro.core import (CheckpointManager, ElasticRuntime, RevocationEvent,
                         SparseCluster)
 from repro.core.transient import LIFETIMES
 from repro.data.pipeline import ShardedDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.obs_args import (add_obs_args, finalize_recorder,
                                    recorder_from_args)
 from repro.models.builder import build_model
@@ -82,7 +83,7 @@ def run_gym(args) -> None:
         policy = GreedyCheapest(n_workers=args.initial_workers)
     else:
         policy = LookaheadMC(seed=args.seed)
-    rec, traced = recorder_from_args(
+    rec = recorder_from_args(
         args, meta={"driver": "gym", "trace": args.trace,
                     "policy": args.policy, "arch": args.arch})
     gym = TransientGym(trace, policy, total_steps=args.gym_total_steps,
@@ -98,7 +99,7 @@ def run_gym(args) -> None:
     del out["epochs"], out["schedule"]          # keep stdout scannable
     out["n_epochs"] = len(ledger.epochs)
     out["n_events"] = len(ledger.schedule)
-    out.update(finalize_recorder(args, rec, traced, clock="sim"))
+    out.update(finalize_recorder(args, rec, clock="sim"))
     print(json.dumps(out, indent=1))
 
 
@@ -147,6 +148,7 @@ def main() -> None:
                          "for the staleness histogram")
     add_obs_args(ap)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.gym:
         run_gym(args)
@@ -166,7 +168,7 @@ def main() -> None:
                         seq_len=args.seq_len, seed=args.seed)
     ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
 
-    rec, traced = recorder_from_args(
+    rec = recorder_from_args(
         args, meta={"driver": "elastic" if args.elastic else "trainer",
                     "arch": args.arch, "steps": args.steps})
     t0 = time.monotonic()
@@ -196,7 +198,7 @@ def main() -> None:
         "elastic": args.elastic,
         "final_step": int(state.step) if hasattr(state, "step") else None,
     }
-    out.update(finalize_recorder(args, rec, traced, clock="sim"))
+    out.update(finalize_recorder(args, rec, clock="sim"))
     print(json.dumps(out, indent=1))
 
 

@@ -36,6 +36,15 @@ class Model:
         key = jax.ShapeDtypeStruct((2,), jnp.uint32)
         return jax.eval_shape(self.init, key)
 
+    def init_for_serving(self, key: jax.Array) -> PyTree:
+        """Raw params for serving, with the ``cast`` leaves held in
+        ``cfg.dtype`` (:func:`repro.models.layers.unbox_for_compute`):
+        the same logits at half the weight bytes. Built as one jitted
+        program, so no eager per-layer trees stay alive beside the
+        stacked ones and no float32 copy outlives the program."""
+        return jax.jit(lambda k: L.unbox_for_compute(
+            self.init(k), self.cfg.dtype))(key)
+
 
 def cache_batch_axes(model: Model, max_len: int = 8,
                      enc_len: int = 0) -> PyTree:

@@ -227,11 +227,9 @@ def _apply_moe_ep(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig
         dsz *= mesh.shape[a]
     xspec = P(dspec, None, None) if B % dsz == 0 else P(None, None, None)
 
-    from repro.kernels import compat
-
     body = functools.partial(_ep_local, cfg=cfg, capacity=C, e_loc=e_loc)
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(xspec,
                   P(None, None),                 # router: replicated
                   P("model", None, None),        # wi: expert-sharded
@@ -363,10 +361,8 @@ def _apply_moe_a2a(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig
         aux = jax.lax.psum(aux, all_axes) / n_ranks
         return out, aux
 
-    from repro.kernels import compat
-
-    fn = compat.shard_map(
-        wrapped, mesh,
+    fn = jax.shard_map(
+        wrapped, mesh=mesh,
         in_specs=(P(bspec, None, None),
                   P(None, None),
                   P(espec, None, None),

@@ -21,16 +21,22 @@ PyTree = Any
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class Boxed:
-    """A parameter leaf carrying logical-axis metadata through the pytree."""
+    """A parameter leaf carrying logical-axis metadata through the pytree.
+
+    ``cast`` marks the leaves the model casts to ``cfg.dtype`` before
+    every use: holding those in ``cfg.dtype`` (:func:`unbox_for_compute`)
+    changes no result and halves their bytes.
+    """
     value: jax.Array
     axes: Tuple[Optional[str], ...]
+    cast: bool = False
 
     def tree_flatten(self):
-        return (self.value,), self.axes
+        return (self.value,), (self.axes, self.cast)
 
     @classmethod
-    def tree_unflatten(cls, axes, children):
-        return cls(children[0], axes)
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
 
 
 def is_boxed(x) -> bool:
@@ -47,8 +53,13 @@ def axes_tree(tree: PyTree) -> PyTree:
     return jax.tree.map(lambda b: b.axes, tree, is_leaf=is_boxed)
 
 
-def boxlike(values: PyTree, axes: PyTree) -> PyTree:
-    return jax.tree.map(Boxed, values, axes)
+def unbox_for_compute(tree: PyTree, dtype) -> PyTree:
+    """Strip Boxed wrappers, holding every ``cast`` leaf in ``dtype``.
+    The model computes the same values from the result as from
+    :func:`unbox`: it casts those leaves to ``dtype`` before every use."""
+    return jax.tree.map(
+        lambda b: b.value.astype(dtype) if b.cast else b.value, tree,
+        is_leaf=is_boxed)
 
 
 class KeyGen:
@@ -64,8 +75,11 @@ class KeyGen:
 
 def param(kg: KeyGen, shape: Sequence[int], axes: Sequence[Optional[str]],
           scale: Optional[float] = None, dtype=jnp.float32,
-          init: str = "normal") -> Boxed:
-    """Create one parameter. ``scale=None`` -> fan-in 1/sqrt(fan_in)."""
+          init: str = "normal", cast: Optional[bool] = None) -> Boxed:
+    """Create one parameter. ``scale=None`` -> fan-in 1/sqrt(fan_in).
+    ``cast`` (see :class:`Boxed`) defaults to True for >=2-D leaves, the
+    weights every layer casts to ``cfg.dtype`` before its matmuls; a
+    >=2-D leaf used in float32 must pass ``cast=False``."""
     shape = tuple(shape)
     assert len(shape) == len(axes), (shape, axes)
     if init == "zeros":
@@ -77,7 +91,7 @@ def param(kg: KeyGen, shape: Sequence[int], axes: Sequence[Optional[str]],
             fan_in = shape[0] if len(shape) > 1 else shape[-1]
             scale = 1.0 / math.sqrt(max(1, fan_in))
         v = (jax.random.normal(kg(), shape, dtype) * scale).astype(dtype)
-    return Boxed(v, tuple(axes))
+    return Boxed(v, tuple(axes), len(shape) > 1 if cast is None else cast)
 
 
 # ---------------------------------------------------------------------------

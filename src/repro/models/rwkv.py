@@ -50,7 +50,8 @@ def init_rwkv_tmix(kg: L.KeyGen, cfg: ModelConfig) -> Dict[str, L.Boxed]:
         "w0": L.param(kg, (d,), ("embed",), init="zeros"),
         "w_lora_a": L.param(kg, (d, LORA_R), ("embed", None), scale=0.01),
         "w_lora_b": L.param(kg, (LORA_R, d), (None, "embed"), scale=0.01),
-        "u": L.param(kg, (H, Dh), ("heads", "head_dim"), scale=0.5),
+        "u": L.param(kg, (H, Dh), ("heads", "head_dim"), scale=0.5,
+                     cast=False),                   # used in float32
         "ln_x": L.param(kg, (d,), ("embed",), init="zeros"),
     }
 

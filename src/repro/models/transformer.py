@@ -53,7 +53,7 @@ def stack_layers(init_fn, n: int, kg: L.KeyGen) -> PyTree:
     trees = [init_fn(kg) for _ in range(n)]
     def _stack(*boxes: L.Boxed) -> L.Boxed:
         v = jnp.stack([b.value for b in boxes])
-        return L.Boxed(v, ("layers",) + boxes[0].axes)
+        return L.Boxed(v, ("layers",) + boxes[0].axes, boxes[0].cast)
     return jax.tree.map(_stack, *trees, is_leaf=L.is_boxed)
 
 
@@ -178,7 +178,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> PyTree:
                   for _ in range(n_blocks)]
         p["blocks"] = jax.tree.map(
             lambda *bs: L.Boxed(jnp.stack([b.value for b in bs]),
-                                ("blocks",) + bs[0].axes),
+                                ("blocks",) + bs[0].axes, bs[0].cast),
             *blocks, is_leaf=L.is_boxed)
         if leftover:
             p["tail"] = stack_layers(lambda k: _init_mamba_layer(k, cfg),

@@ -23,9 +23,9 @@ package gives them one instrumentation seam:
                consumes as a first-class scale-up signal.
 ``report``     self-contained HTML/text ops report (sparklines, alert
                table, per-replica summary) from the above artifacts.
-``profiling``  opt-in ``jax.profiler`` bridge (``annotate_span``,
-               ``start_trace``) so device traces line up with sim events;
-               the only module here that touches jax, lazily.
+``profiling``  ``jax.profiler`` bridge (``annotate_span``) so device
+               traces line up with sim events; the only module here that
+               touches jax, and not imported by this package.
 
 The core modules (events/metrics/export) are dependency-light on purpose:
 stdlib only, importable before jax, usable from the pure-NumPy simulation

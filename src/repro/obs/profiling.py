@@ -1,97 +1,22 @@
-"""Opt-in ``jax.profiler`` bridge — device traces aligned with sim events.
-
-The only obs module that touches jax, and only lazily: the simulation
-stack imports ``repro.obs`` without paying for (or requiring) jax.
+"""``jax.profiler`` bridge — device traces aligned with sim events.
 
 ``annotate_span(name)`` is the seam kernel dispatch and train steps wrap:
-inside a jit trace it lowers to ``jax.named_scope`` (the name survives
-into HLO and shows up on device timelines); at op-dispatch time it also
-enters ``jax.profiler.TraceAnnotation`` when the running jax has one.
-Both degrade to a no-op on jax versions/backends that lack the API —
-same graceful-drift policy as ``kernels/compat.py``.
-
-``TraceContext`` combines a ``Recorder`` span with the jax annotation so
-one ``with`` statement lands the event in the JSONL log *and* the device
-trace under the same name — which is what lets a Perfetto view of
-``jax.profiler.start_trace`` output be cross-referenced against the sim
-event log.
+inside a jit trace its ``jax.named_scope`` puts the name into the HLO, so
+it shows up on device timelines; at op-dispatch time its
+``jax.profiler.TraceAnnotation`` marks the host thread under the same
+name. The package's core modules stay jax-free: only code that already
+runs jax imports this one.
 """
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Iterator, Optional
+from typing import Iterator
 
-from repro.obs.events import CAT_KERNEL, NULL, Recorder
-
-_WARNED: set = set()
-
-
-def _jax():
-    try:
-        import jax
-        return jax
-    except Exception:                                  # pragma: no cover
-        return None
+import jax
 
 
 @contextlib.contextmanager
 def annotate_span(name: str) -> Iterator[None]:
-    """Name a region for device profiling; no-op without jax support."""
-    jax = _jax()
-    with contextlib.ExitStack() as stack:
-        if jax is not None:
-            named_scope = getattr(jax, "named_scope", None)
-            if named_scope is not None:
-                stack.enter_context(named_scope(name))
-            ann = getattr(getattr(jax, "profiler", None),
-                          "TraceAnnotation", None)
-            if ann is not None:
-                try:
-                    stack.enter_context(ann(name))
-                except Exception:
-                    pass        # annotation is best-effort, never fatal
+    """Name a region for device profiling."""
+    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
         yield
-
-
-@contextlib.contextmanager
-def TraceContext(recorder: Optional[Recorder], name: str, *,
-                 cat: str = CAT_KERNEL, track: str = "main",
-                 **args: Any) -> Iterator[Any]:
-    """Recorder span + device annotation under one name."""
-    rec = recorder or NULL
-    with annotate_span(name):
-        with rec.span(name, cat=cat, track=track, **args) as live:
-            yield live
-
-
-def start_trace(log_dir: str) -> bool:
-    """Start a jax profiler trace into ``log_dir``; False if unsupported
-    (missing API, unsupported backend) — callers proceed untraced."""
-    jax = _jax()
-    start = getattr(getattr(jax, "profiler", None), "start_trace", None) \
-        if jax is not None else None
-    if start is None:
-        return False
-    try:
-        start(log_dir)
-        return True
-    except Exception as e:                             # pragma: no cover
-        if "start_trace" not in _WARNED:
-            _WARNED.add("start_trace")
-            print(f"[obs] jax.profiler.start_trace unavailable: {e!r}; "
-                  "continuing without a device trace")
-        return False
-
-
-def stop_trace() -> bool:
-    """Stop a running jax profiler trace; False if none/unsupported."""
-    jax = _jax()
-    stop = getattr(getattr(jax, "profiler", None), "stop_trace", None) \
-        if jax is not None else None
-    if stop is None:
-        return False
-    try:
-        stop()
-        return True
-    except Exception:                                  # pragma: no cover
-        return False

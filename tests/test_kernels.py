@@ -5,9 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# kernels/compat.py resolves pltpu.CompilerParams vs TPUCompilerParams and
-# jax.shard_map vs jax.experimental.shard_map at call time, so these sweeps
-# run un-skipped on both the 0.4.x and >=0.5 toolchains (ISSUE 6).
 from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_ref)
 from repro.kernels.flash_attention import attention_ref, flash_attention

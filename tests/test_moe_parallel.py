@@ -14,10 +14,6 @@ from repro.sharding import param_spec, use_mesh
 
 ARCHS = ("moonshot-v1-16b-a3b", "arctic-480b")
 
-# The ep/a2a MoE paths route shard_map through kernels/compat.py, which
-# resolves jax.shard_map (>=0.5) vs jax.experimental.shard_map (0.4.x) and
-# translates check_vma<->check_rep — so these run on both toolchains.
-
 
 @pytest.fixture(scope="module")
 def mesh():
