@@ -38,7 +38,7 @@ from repro.config import ModelConfig, TrainConfig
 from repro.core.cluster import SparseCluster
 from repro.models import modality
 from repro.models.builder import Model
-from repro.obs.profiling import annotate_span
+from repro.obs.profiling import region
 from repro.train.step import TrainState, cross_entropy, _token_weights
 
 PyTree = Any
@@ -64,16 +64,18 @@ def _make_row_weighted_loss(model: Model, tcfg: TrainConfig) -> Callable:
             batch)
         remat = tcfg.remat != "none"
         logits, aux = model.apply(params, flat, remat=remat)
-        if cfg.family == "resnet":
-            lse = jax.nn.logsumexp(logits.astype(jnp.float32), -1)
-            onehot = jax.nn.one_hot(flat["labels"], logits.shape[-1],
-                                    dtype=jnp.float32)
-            nll = lse - jnp.sum(onehot * logits.astype(jnp.float32), -1)
-            loss = jnp.sum(nll * row_w) / jnp.maximum(jnp.sum(row_w), 1.0)
-        else:
-            S = logits.shape[1]
-            w = _token_weights(cfg, flat, S) * row_w[:, None]
-            loss = cross_entropy(logits, flat["labels"], w)
+        with region("head"):
+            if cfg.family == "resnet":
+                lse = jax.nn.logsumexp(logits.astype(jnp.float32), -1)
+                onehot = jax.nn.one_hot(flat["labels"], logits.shape[-1],
+                                        dtype=jnp.float32)
+                nll = lse - jnp.sum(onehot * logits.astype(jnp.float32), -1)
+                loss = jnp.sum(nll * row_w) / jnp.maximum(jnp.sum(row_w),
+                                                          1.0)
+            else:
+                S = logits.shape[1]
+                w = _token_weights(cfg, flat, S) * row_w[:, None]
+                loss = cross_entropy(logits, flat["labels"], w)
         total = loss + cfg.router_aux_coef * aux
         return total, {"loss": loss, "aux": aux}
 
@@ -84,20 +86,17 @@ def _apply_grads(state: TrainState, grads, lr_scale, tcfg: TrainConfig,
                  opt, sched, metrics) -> Tuple[TrainState, Dict]:
     from repro.optim.optimizers import clip_by_global_norm, global_norm
 
-    # named for device traces: this is the gradient-aggregation region —
-    # under SPMD lowering the cross-replica reduction sits here, which is
-    # exactly the PS-bottleneck communication the paper's Fig 6 measures
-    with annotate_span(obs.EV_ALLREDUCE):
+    with region("update"):
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         if tcfg.optimizer.grad_clip > 0:
             grads, gnorm = clip_by_global_norm(grads,
                                                tcfg.optimizer.grad_clip)
         else:
             gnorm = global_norm(grads)
-    lr = tcfg.optimizer.lr * sched(state.step) * lr_scale
-    updates, new_opt = opt.update(grads, state.opt, state.params, lr)
-    new_params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
-                              state.params, updates)
+        lr = tcfg.optimizer.lr * sched(state.step) * lr_scale
+        updates, new_opt = opt.update(grads, state.opt, state.params, lr)
+        new_params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
+                                  state.params, updates)
     new_state = TrainState(params=new_params, opt=new_opt,
                            step=state.step + 1)
     return new_state, dict(metrics, grad_norm=gnorm, lr=lr)
@@ -309,19 +308,20 @@ class ElasticRuntime:
             if self.cluster.n_active == 0:
                 raise RuntimeError(f"no active workers at step {step}")
             t0 = rec.now()
-            batch, mask = slot_batch(self.model.cfg, self.dataset, step,
-                                     self.cluster)
-            if self.allocator is not None:
-                per = next(iter(batch.values())).shape[1]
-                alloc = self.allocator.allocation()
-                counts = np.minimum(alloc.counts, per)   # layout capacity
-                state, m = self.step_fn(state, batch,
-                                        jnp.asarray(counts, jnp.float32),
-                                        jnp.float32(alloc.lr_ratio))
-            else:
-                state, m = self.step_fn(state, batch, mask)
-            loss = float(m["loss"])
-            n_active = int(m["active"])
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                batch, mask = slot_batch(self.model.cfg, self.dataset, step,
+                                         self.cluster)
+                if self.allocator is not None:
+                    per = next(iter(batch.values())).shape[1]
+                    alloc = self.allocator.allocation()
+                    counts = np.minimum(alloc.counts, per)  # layout capacity
+                    state, m = self.step_fn(state, batch,
+                                            jnp.asarray(counts, jnp.float32),
+                                            jnp.float32(alloc.lr_ratio))
+                else:
+                    state, m = self.step_fn(state, batch, mask)
+                loss = float(m["loss"])
+                n_active = int(m["active"])
             self.metrics_log.append(
                 {"step": step, "loss": loss,
                  "active": n_active, "lr": float(m["lr"])})

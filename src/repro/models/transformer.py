@@ -39,6 +39,7 @@ from repro.models import ffn as F
 from repro.models import layers as L
 from repro.models import rwkv as R
 from repro.models import ssm as M
+from repro.obs.profiling import region
 from repro.sharding import shard_act
 
 PyTree = Any
@@ -74,25 +75,28 @@ def _window_schedule(cfg: ModelConfig, n_layers: int) -> jnp.ndarray:
 
 def _attn_block(lp, x, cfg: ModelConfig, *, window, positions,
                 mrope_positions=None, causal=True):
-    h = L.rms_norm(x, lp["ln1"]["gamma"], cfg.norm_eps)
-    q, k, v = A.project_qkv(lp["attn"], h, cfg, positions=positions,
-                            mrope_positions=mrope_positions)
-    att = A.attend(q, k, v, cfg, causal=causal, window=window)
-    att = shard_act(att, ("batch", None, "heads", None))
-    return x + A.out_proj(lp["attn"], att)
+    with region("attn"):
+        h = L.rms_norm(x, lp["ln1"]["gamma"], cfg.norm_eps)
+        q, k, v = A.project_qkv(lp["attn"], h, cfg, positions=positions,
+                                mrope_positions=mrope_positions)
+        att = A.attend(q, k, v, cfg, causal=causal, window=window)
+        att = shard_act(att, ("batch", None, "heads", None))
+        return x + A.out_proj(lp["attn"], att)
 
 
 def _mlp_block(lp, x, cfg: ModelConfig):
-    h = L.rms_norm(x, lp["ln2"]["gamma"], cfg.norm_eps)
-    h = shard_act(h, ("batch", None, None))
-    return x + F.apply_mlp(lp["mlp"], h)
+    with region("mlp"):
+        h = L.rms_norm(x, lp["ln2"]["gamma"], cfg.norm_eps)
+        h = shard_act(h, ("batch", None, None))
+        return x + F.apply_mlp(lp["mlp"], h)
 
 
 def _moe_block(lp, x, cfg: ModelConfig):
-    h = L.rms_norm(x, lp["ln2"]["gamma"], cfg.norm_eps)
-    out, aux = F.apply_moe(lp["moe"], h, cfg)
-    out = shard_act(out, ("batch", None, None))
-    return x + out, aux
+    with region("mlp"):
+        h = L.rms_norm(x, lp["ln2"]["gamma"], cfg.norm_eps)
+        out, aux = F.apply_moe(lp["moe"], h, cfg)
+        out = shard_act(out, ("batch", None, None))
+        return x + out, aux
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +219,8 @@ def num_shared_invocations(cfg: ModelConfig) -> int:
 def _scan(body, x, xs, cfg: ModelConfig, remat: bool = True):
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
-    return jax.lax.scan(body, x, xs)
+    with region("layers"):
+        return jax.lax.scan(body, x, xs)
 
 
 def _dense_trunk(params, cfg: ModelConfig, x, positions, mrope_positions=None,
@@ -272,7 +277,8 @@ def _hybrid_trunk(params, cfg: ModelConfig, x, positions, remat=True):
         return h, None
 
     body = jax.checkpoint(block_body, prevent_cse=False) if remat else block_body
-    x, _ = jax.lax.scan(body, x, params["blocks"])
+    with region("layers"):
+        x, _ = jax.lax.scan(body, x, params["blocks"])
     if "tail" in params:
         x, _ = _scan(mamba_body, x, params["tail"], cfg, remat)
     return x
@@ -335,18 +341,21 @@ def forward(params: PyTree, cfg: ModelConfig, batch: Dict[str, jax.Array],
 
     if fam == "encdec":
         enc_x = batch["frame_embeds"].astype(dt)
-        dec_x = L.embed(params["embed"], batch["tokens"], dt)
+        with region("embed"):
+            dec_x = L.embed(params["embed"], batch["tokens"], dt)
         dec_x = shard_act(dec_x, ("batch", None, None))
         pos = jnp.arange(dec_x.shape[1])[None, :]
         x = _encdec_trunk(params, cfg, enc_x, dec_x, pos, remat=remat)
     else:
         if fam == "vlm":
-            tok_x = L.embed(params["embed"], batch["tokens"], dt)
+            with region("embed"):
+                tok_x = L.embed(params["embed"], batch["tokens"], dt)
             x = jnp.concatenate([batch["patch_embeds"].astype(dt), tok_x], axis=1)
             mrope_pos = batch["mrope_positions"]
             pos = None
         else:
-            x = L.embed(params["embed"], batch["tokens"], dt)
+            with region("embed"):
+                x = L.embed(params["embed"], batch["tokens"], dt)
             mrope_pos = None
             pos = jnp.arange(x.shape[1])[None, :]
         x = shard_act(x, ("batch", None, None))
@@ -362,9 +371,10 @@ def forward(params: PyTree, cfg: ModelConfig, batch: Dict[str, jax.Array],
         else:
             raise ValueError(fam)
 
-    x = L.rms_norm(x, params["final_norm"]["gamma"], cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg.tie_embeddings)
-    logits = shard_act(logits, ("batch", None, "vocab"))
+    with region("head"):
+        x = L.rms_norm(x, params["final_norm"]["gamma"], cfg.norm_eps)
+        logits = L.unembed(params["embed"], x, cfg.tie_embeddings)
+        logits = shard_act(logits, ("batch", None, "vocab"))
     return logits, aux
 
 

@@ -23,9 +23,10 @@ package gives them one instrumentation seam:
                consumes as a first-class scale-up signal.
 ``report``     self-contained HTML/text ops report (sparklines, alert
                table, per-replica summary) from the above artifacts.
-``profiling``  ``jax.profiler`` bridge (``annotate_span``) so device
-               traces line up with sim events; the only module here that
-               touches jax, and not imported by this package.
+``profiling``  ``jax.profiler`` bridge: ``region``/``annotate_span``
+               name traced code in the compiled program, so device
+               traces split by region; the only module here that touches
+               jax, and not imported by this package.
 
 The core modules (events/metrics/export) are dependency-light on purpose:
 stdlib only, importable before jax, usable from the pure-NumPy simulation
@@ -33,9 +34,9 @@ stack without dragging in the training stack.
 """
 from repro.obs.events import (CAT_BENCH, CAT_GYM, CAT_KERNEL,  # noqa: F401
                               CAT_POLICY, CAT_SERVE, CAT_SIM, CAT_TRAIN,
-                              EV_ALERT, EV_ALLREDUCE, EV_COMPLETE, EV_DECODE,
-                              EV_DRAIN, EV_ENQUEUE, EV_EPISODE, EV_MIGRATE,
-                              EV_PREFILL, EV_REJECT, EV_REPLAN, EV_REVOKE_FIRE,
+                              EV_ALERT, EV_COMPLETE, EV_DECODE, EV_DRAIN,
+                              EV_ENQUEUE, EV_EPISODE, EV_MIGRATE, EV_PREFILL,
+                              EV_REJECT, EV_REPLAN, EV_REVOKE_FIRE,
                               EV_REVOKE_WARN, EV_SLOT_JOIN, EV_SLOT_RELEASE,
                               EV_SLOT_REQUEST, EV_STEP, EV_TRIAL_DONE,
                               TAXONOMY, Event, NULL, NullRecorder, Recorder,

@@ -51,7 +51,6 @@ EV_SLOT_RELEASE = "slot.release"       # policy released the server
 EV_SLOT_REQUEST = "slot.request"       # join requested (activation pending)
 EV_REPLAN = "replan"                   # policy decision span
 EV_STEP = "step"                       # one training step / sim segment
-EV_ALLREDUCE = "allreduce"             # gradient sync inside a step
 EV_PREFILL = "prefill"                 # serving: prompt ingestion span
 EV_DECODE = "decode"                   # serving: token generation span
 EV_ENQUEUE = "request.enqueue"         # serving: request submitted
@@ -71,7 +70,6 @@ TAXONOMY = {
     EV_SLOT_REQUEST: "join requested; activation pending JOIN_OVERHEAD_S",
     EV_REPLAN: "policy observed the market and chose a fleet",
     EV_STEP: "one training step (train) / constant-rate segment (sim/gym)",
-    EV_ALLREDUCE: "gradient synchronization inside a step",
     EV_PREFILL: "serving: prompt tokens fed through the decode path",
     EV_DECODE: "serving: autoregressive token generation",
     EV_ENQUEUE: "serving: request entered the queue",

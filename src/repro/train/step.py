@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig, TrainConfig
 from repro.models import modality
 from repro.models.builder import Model
+from repro.obs.profiling import region
 from repro.optim import make_optimizer, make_schedule
 from repro.optim.optimizers import clip_by_global_norm
 
@@ -80,12 +81,13 @@ def loss_fn(model: Model, params: PyTree, batch: Dict[str, jax.Array],
     cfg = model.cfg
     remat = tcfg.remat != "none"
     logits, aux = model.apply(params, batch, remat=remat)
-    if cfg.family == "resnet":
-        loss = cross_entropy(logits, batch["labels"])
-    else:
-        S = logits.shape[1]
-        w = _token_weights(cfg, batch, S)
-        loss = cross_entropy(logits, batch["labels"], w)
+    with region("head"):
+        if cfg.family == "resnet":
+            loss = cross_entropy(logits, batch["labels"])
+        else:
+            S = logits.shape[1]
+            w = _token_weights(cfg, batch, S)
+            loss = cross_entropy(logits, batch["labels"], w)
     total = loss + cfg.router_aux_coef * aux
     return total, {"loss": loss, "aux": aux}
 
@@ -168,17 +170,19 @@ def make_train_step(model: Model, tcfg: TrainConfig, param_shardings=None,
         else:
             grads, metrics = grads_of(state.params, batch)
 
-        grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        if tcfg.optimizer.grad_clip > 0:
-            grads, gnorm = clip_by_global_norm(grads, tcfg.optimizer.grad_clip)
-        else:
-            from repro.optim.optimizers import global_norm
-            gnorm = global_norm(grads)
+        with region("update"):
+            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            if tcfg.optimizer.grad_clip > 0:
+                grads, gnorm = clip_by_global_norm(grads,
+                                                   tcfg.optimizer.grad_clip)
+            else:
+                from repro.optim.optimizers import global_norm
+                gnorm = global_norm(grads)
 
-        lr = base_lr * sched(state.step) * lr_scale
-        updates, new_opt = opt.update(grads, state.opt, state.params, lr)
-        new_params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
-                                  state.params, updates)
+            lr = base_lr * sched(state.step) * lr_scale
+            updates, new_opt = opt.update(grads, state.opt, state.params, lr)
+            new_params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
+                                      state.params, updates)
         new_state = TrainState(params=new_params, opt=new_opt,
                                step=state.step + 1)
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)

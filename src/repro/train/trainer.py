@@ -80,8 +80,9 @@ class Trainer:
         t0 = time.monotonic()
         for step in range(start, start + num_steps):
             ts = rec.now()
-            batch = self.dataset.global_batch_at(step)
-            state, m = self.step_fn(state, batch, jnp.float32(lr_scale))
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                batch = self.dataset.global_batch_at(step)
+                state, m = self.step_fn(state, batch, jnp.float32(lr_scale))
             if rec.enabled:
                 dt = rec.now() - ts
                 rec.span_at(obs.EV_STEP, cat=obs.CAT_TRAIN, t_wall=ts,
