@@ -84,13 +84,13 @@ Case = Tuple[str, Callable[[], jax.Array], Callable[[], jax.Array],
 
 def _flash_case(B, H, KV, S, D, causal=True, blk=128) -> Case:
     from repro.kernels.flash_attention import attention_ref, flash_attention
-    q = _arr((B, H, S, D))
-    k, v = _arr((B, KV, S, D)), _arr((B, KV, S, D))
+    q = _arr((B, S, H, D))
+    k, v = _arr((B, S, KV, D)), _arr((B, S, KV, D))
     flops = 4.0 * B * H * S * S * D * (0.5 if causal else 1.0)
     bytes_ = (q.size + 2 * k.size + q.size) * q.dtype.itemsize
     interp = jax.default_backend() == "cpu"
-    pallas = lambda: flash_attention(q, k, v, causal=causal, blk_q=blk,
-                                     blk_k=blk, interpret=interp)
+    pallas = lambda: flash_attention(q, k, v, causal=causal,
+                                     blocks=(blk, blk), interpret=interp)
     ref_f = jax.jit(functools.partial(attention_ref, causal=causal))
     ref = lambda: ref_f(q, k, v)
     return (f"flash/B{B}H{H}KV{KV}S{S}D{D}", pallas, ref, flops, bytes_)

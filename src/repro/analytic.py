@@ -213,6 +213,8 @@ def step_hbm_bytes(model, cfg: ModelConfig, shape: ShapeConfig, mesh, *,
     from repro.sharding import data_size
 
     impl = attn_impl or cfg.attn_impl
+    if impl == "auto":        # the kernel only where the mesh is a TPU's
+        impl = "pallas" if mesh.devices.flat[0].platform == "tpu" else "xla"
     layout = tcfg.layout if tcfg else "tp"
     dsz = data_size(mesh, layout)
     chips = mesh.size

@@ -95,7 +95,10 @@ class ModelConfig:
     param_dtype: str = "float32"
 
     # --- implementation selection (xla = pure jnp; pallas = TPU kernel) ----
-    attn_impl: str = "xla"
+    # attn_impl "auto": full attention takes the flash kernel where the
+    # program compiles for a TPU and the shapes tile (models/attention.py);
+    # decode keeps the XLA path unless "pallas"
+    attn_impl: str = "auto"
     ssm_impl: str = "xla"
     rwkv_impl: str = "xla"
     moe_impl: str = "gspmd"        # "gspmd" (auto) | "ep" (shard_map expert

@@ -6,25 +6,23 @@ import jax.numpy as jnp
 
 
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                  causal: bool = True, window: int = 0,
+                  causal: bool = True, window=0,
                   sm_scale: float | None = None) -> jax.Array:
-    """q: (B, H, Sq, D); k/v: (B, KV, Sk, D) -> (B, H, Sq, D)."""
-    B, H, Sq, D = q.shape
-    _, KV, Sk, _ = k.shape
+    """q: (B, Sq, H, D); k/v: (B, Sk, KV, D) -> (B, Sq, H, D)."""
+    B, Sq, H, D = q.shape
+    _, Sk, KV, _ = k.shape
     group = H // KV
     if sm_scale is None:
         sm_scale = D ** -0.5
-    kf = jnp.repeat(k, group, axis=1).astype(jnp.float32)
-    vf = jnp.repeat(v, group, axis=1).astype(jnp.float32)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), kf) * sm_scale
+    kf = jnp.repeat(k, group, axis=2).astype(jnp.float32)
+    vf = jnp.repeat(v, group, axis=2).astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kf) * sm_scale
     q_pos = jnp.arange(Sq)[:, None]
     k_pos = jnp.arange(Sk)[None, :]
-    mask = jnp.ones((Sq, Sk), bool)
+    mask = (window <= 0) | (k_pos > q_pos - window)
     if causal:
         mask &= k_pos <= q_pos
-    if window > 0:
-        mask &= k_pos > q_pos - window
     s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)          # fully-masked rows -> 0
-    return jnp.einsum("bhqk,bhkd->bhqd", p, vf).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vf).astype(q.dtype)

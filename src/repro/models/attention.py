@@ -1,10 +1,22 @@
 """GQA/MQA/MHA attention: training/prefill (blockwise) and decode paths.
 
-The XLA path computes attention in query chunks (``cfg.attn_chunk``) so the
-materialized score block is (B, kvh, g, Cq, Skv) instead of the full
-(B, H, S, S) — the jnp analogue of a flash kernel's HBM footprint. The
-Pallas fast path lives in ``repro.kernels`` and is selected with
-``cfg.attn_impl == "pallas"``.
+Full attention (``attend``) has two paths, chosen by ``cfg.attn_impl``:
+
+- the Pallas flash kernel (``repro.kernels.flash_attention``), which
+  skips whole masked blocks and never writes a score block to HBM, with
+  its own backward. ``"auto"`` (the default) takes it where the traced
+  program compiles for a TPU, ``kv_len`` is None and the shapes tile the
+  kernel's blocks; ``"pallas"`` takes it wherever ``kv_len`` is None.
+  Under a mesh it runs per shard, and where the mesh cannot hold it per
+  shard both fall back to the XLA path.
+- the XLA path, which computes attention in query chunks
+  (``cfg.attn_chunk``) so the materialized score block is
+  (B, kvh, g, Cq, Skv) instead of the full (B, H, S, S). ``"xla"`` forces
+  it.
+
+Each path runs under its own named scope (``kernel.flash_attention.pallas``,
+``kernel.attention.xla_scan``), so a trace tells which one ran. Decode
+(``attend_decode``) takes its kernel only under ``"pallas"``.
 
 Sliding windows are passed as *per-layer runtime scalars* so a scan over
 layers can mix local and global layers (gemma3's 5:1 pattern):
@@ -18,7 +30,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.kernels.flash_attention import ops as flash
 from repro.models import layers as L
+from repro.obs.profiling import annotate_span
 
 
 # ---------------------------------------------------------------------------
@@ -101,13 +115,31 @@ def _chunk_attend(q_chunk: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
 
 
+def _use_flash(q: jax.Array, k: jax.Array, cfg: ModelConfig,
+               kv_len) -> bool:
+    if kv_len is not None:
+        return False
+    if cfg.attn_impl == "pallas":
+        return True
+    return (cfg.attn_impl == "auto" and flash.platform() == "tpu"
+            and flash.tiles(q.shape[1], k.shape[1], q.shape[-1]))
+
+
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig, *,
            causal: bool = True, window=0,
            kv_len: Optional[jax.Array] = None) -> jax.Array:
-    """Full attention, q-chunked. q: (B,S,H,Dh), k/v: (B,Skv,KV,Dh)."""
-    if cfg.attn_impl == "pallas" and kv_len is None:
-        from repro.kernels.flash_attention.ops import attention as flash
-        return flash(q, k, v, causal=causal, window=window)
+    """Full attention. q: (B,S,H,Dh), k/v: (B,Skv,KV,Dh)."""
+    if _use_flash(q, k, cfg, kv_len):
+        out = flash.attention(q, k, v, causal=causal, window=window)
+        if out is not None:
+            return out
+    with annotate_span("kernel.attention.xla_scan"):
+        return _attend_xla(q, k, v, cfg, causal=causal, window=window,
+                           kv_len=kv_len)
+
+
+def _attend_xla(q, k, v, cfg: ModelConfig, *, causal, window, kv_len):
+    """The q-chunked XLA path."""
     B, S, H, Dh = q.shape
     KV = k.shape[2]
     G = H // KV

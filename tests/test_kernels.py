@@ -29,33 +29,90 @@ FLASH_CASES = [
 ]
 
 
+FLASH_TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+def _flash_inputs(case, dtype, rng=RNG):
+    B, H, KV, Sq, Sk, D = case[:6]
+    draw = lambda shape: jnp.asarray(rng.normal(size=shape), dtype)
+    return draw((B, Sq, H, D)), draw((B, Sk, KV, D)), draw((B, Sk, KV, D))
+
+
 @pytest.mark.parametrize("case", FLASH_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(case, dtype):
-    B, H, KV, Sq, Sk, D, causal, win, bq, bk = case
-    q, k, v = (_arr((B, H, Sq, D), dtype), _arr((B, KV, Sk, D), dtype),
-               _arr((B, KV, Sk, D), dtype))
+    causal, win, bq, bk = case[6:]
+    q, k, v = _flash_inputs(case, dtype)
     out = flash_attention(q, k, v, causal=causal, window=win,
-                          blk_q=bq, blk_k=bk, interpret=True)
+                          blocks=(bq, bk), interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=win)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    tol = FLASH_TOL[dtype]
     np.testing.assert_allclose(out.astype(jnp.float32),
                                ref.astype(jnp.float32), atol=tol, rtol=tol)
+
+
+def _grads(fn, q, k, v, do):
+    """d/d(q, k, v) of sum(fn(q, k, v) * do), in f32."""
+    loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * do)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("case", FLASH_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad(case, dtype):
+    """The custom-VJP backward (dK/dV and dQ kernels) against autodiff of
+    the oracle, at the forward's tolerances."""
+    causal, win, bq, bk = case[6:]
+    rng = np.random.default_rng(FLASH_CASES.index(case))
+    q, k, v = _flash_inputs(case, dtype, rng)
+    do = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+    got = _grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=win, blocks=(bq, bk),
+        interpret=True), q, k, v, do)
+    want = _grads(lambda q, k, v: attention_ref(
+        q, k, v, causal=causal, window=win), q, k, v, do)
+    tol = FLASH_TOL[dtype]
+    for name, g, w in zip("qkv", got, want):
+        assert g.dtype == dtype, name
+        np.testing.assert_allclose(g.astype(jnp.float32),
+                                   w.astype(jnp.float32), atol=tol,
+                                   rtol=tol, err_msg=f"d{name}")
 
 
 def test_flash_attention_traced_window():
     """gemma3 scans per-layer windows: the same jitted kernel must serve
     traced window values without retracing."""
-    q = _arr((1, 2, 64, 32), jnp.float32)
-    k = v = _arr((1, 2, 64, 32), jnp.float32)
+    q = _arr((1, 64, 2, 32), jnp.float32)
+    k = v = _arr((1, 64, 2, 32), jnp.float32)
 
     @jax.jit
     def f(win):
-        return flash_attention(q, k, v, window=win, blk_q=32, blk_k=32,
+        return flash_attention(q, k, v, window=win, blocks=(32, 32),
                                interpret=True)
     for w in (0, 8, 32):
         np.testing.assert_allclose(
             f(jnp.int32(w)), attention_ref(q, k, v, window=w), atol=2e-5)
+
+
+def test_flash_attention_grad_traced_window():
+    """The backward takes the traced window too: one jitted gradient
+    serves every window."""
+    rng = np.random.default_rng(7)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 64, 4, 32)), jnp.float32)
+               for _ in range(3))
+    k, v = k[:, :, :2], v[:, :, :2]
+    do = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+
+    @jax.jit
+    def f(win):
+        return _grads(lambda q, k, v: flash_attention(
+            q, k, v, window=win, blocks=(32, 32), interpret=True),
+            q, k, v, do)
+    for w in (0, 8, 32):
+        want = _grads(lambda q, k, v: attention_ref(q, k, v, window=w),
+                      q, k, v, do)
+        for g, r in zip(f(jnp.int32(w)), want):
+            np.testing.assert_allclose(g, r, atol=2e-5, rtol=2e-5)
 
 
 DECODE_CASES = [
@@ -177,3 +234,35 @@ def test_model_xla_vs_pallas_forward():
         op, _ = mp.apply(params, batch, remat=False)
         scale = float(jnp.max(jnp.abs(ox)))
         assert float(jnp.max(jnp.abs(ox - op))) < 1e-4 * max(1, scale), arch
+
+
+@pytest.mark.parametrize("arch", ["starcoder2-3b", "gemma3-27b"])
+def test_train_step_pallas_matches_xla(arch):
+    """One full-remat train step through the flash kernel's backward gives
+    the XLA path's loss and gradients (gemma3: traced per-layer windows).
+    With momentum from zero and no decay or clip, the new momentum is the
+    gradient."""
+    from repro.config import OptimizerConfig, TrainConfig, get_config
+    from repro.data.pipeline import make_batch
+    from repro.models.builder import build_model
+    from repro.train.step import init_state, make_train_step
+
+    tcfg = TrainConfig(optimizer=OptimizerConfig(weight_decay=0.0,
+                                                 grad_clip=0.0),
+                       remat="full")
+    cfg = get_config(arch, reduced=True).replace(dtype="float32")
+    state = init_state(build_model(cfg), tcfg, jax.random.key(0))
+    batch = make_batch(cfg, 2, 64)
+    out = {}
+    for impl in ("xla", "pallas"):
+        step = jax.jit(make_train_step(build_model(cfg.replace(
+            attn_impl=impl)), tcfg))
+        new, metrics = step(state, batch)
+        out[impl] = (metrics["loss"], new.opt["mu"])
+    (loss_x, g_x), (loss_p, g_p) = out["xla"], out["pallas"]
+    np.testing.assert_allclose(loss_p, loss_x, rtol=1e-5)
+    for (path, gx), gp in zip(jax.tree_util.tree_leaves_with_path(g_x),
+                              jax.tree.leaves(g_p)):
+        scale = max(1.0, float(jnp.max(jnp.abs(gx))))
+        np.testing.assert_allclose(gp, gx, atol=1e-4 * scale,
+                                   err_msg=jax.tree_util.keystr(path))

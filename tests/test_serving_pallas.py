@@ -38,7 +38,7 @@ def _reqs(cfg, n, seed=0, max_new=6, plen=5):
 
 def test_engine_decode_pallas_matches_xla(setup):
     cfg, model, params = setup
-    assert cfg.attn_impl == "xla"          # baseline engine is the ref
+    assert cfg.attn_impl != "pallas"       # baseline engine is the ref
     reqs_x, reqs_p = _reqs(cfg, 3, seed=5), _reqs(cfg, 3, seed=5)
 
     eng_x = ServeEngine(model, params, max_batch=3, max_len=32)

@@ -11,19 +11,26 @@ loading the TPU compiler takes a process-wide lock, and every test worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
-from repro.config import get_config
+from repro.config import TrainConfig, get_config
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rwkv6 import rwkv6_scan
 from repro.kernels.ssd_scan import ssd_scan
+from repro.launch.specs import batch_shardings
+from repro.models import attention as A
 from repro.models.builder import build_model
-from repro.train.step import make_paged_serve_step, make_serve_step
+from repro.sharding import param_shardings, use_mesh
+from repro.train.step import (TrainState, init_state, make_paged_serve_step,
+                              make_serve_step, make_train_step)
 
 # The compiler's own limit on one v5e chip, as it reports it when a
 # program does not fit ("... of 15.75G hbm").
@@ -32,16 +39,27 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The benchmark's fsdp mesh: 4 x 1 over a v5e:2x2 host."""
+    return Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
 
 
 def _on(sharding, tree):
@@ -71,8 +89,8 @@ def _kernel_cases():
         # starcoder2-3b causal prefill of one 1024-token prompt
         "flash_attention": (
             lambda q, k, v: flash_attention(q, k, v, causal=True),
-            [((1, H, S, D), BF16), ((1, KV, S, D), BF16),
-             ((1, KV, S, D), BF16)]),
+            [((1, S, H, D), BF16), ((1, S, KV, D), BF16),
+             ((1, S, KV, D), BF16)]),
         # zamba2-1.2b Mamba2 SSD scan at its own chunk length
         "ssd_scan": (
             lambda x, b, c, a: ssd_scan(x, b, c, a, chunk=zb.ssm_chunk),
@@ -122,3 +140,102 @@ def test_full_width_decode_step_fits_one_v5e(one_chip, cache_impl):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+# ---------------------------------------------------------------------------
+# Attention dispatch and the full-width fsdp train step on four chips
+# ---------------------------------------------------------------------------
+
+def _attend_jaxpr(mesh, B=4, S=2048, D=128, kv_len=None, impl="auto"):
+    cfg = get_config("starcoder2-3b").replace(attn_impl=impl)
+    q = jax.ShapeDtypeStruct((B, S, cfg.num_heads, D), BF16)
+    kv = jax.ShapeDtypeStruct((B, S, cfg.num_kv_heads, D), BF16)
+    with use_mesh(mesh, "fsdp"):
+        return str(jax.make_jaxpr(lambda q, k, v: A.attend(
+            q, k, v, cfg, kv_len=kv_len))(q, kv, kv))
+
+
+def test_auto_takes_the_kernel_per_shard_on_v5e(four_chips):
+    text = _attend_jaxpr(four_chips)
+    assert "pallas_call" in text and "shard_map" in text
+
+
+@pytest.mark.parametrize("case", [
+    dict(S=2000),                    # positions do not tile the blocks
+    dict(D=64),                      # head_dim off the lanes
+    dict(B=2),                       # batch does not divide the data axes
+    dict(kv_len=jnp.int32(5)),       # masked kv lengths
+])
+def test_auto_falls_back_to_the_scan(four_chips, case):
+    text = _attend_jaxpr(four_chips, **case)
+    assert "pallas_call" not in text and "shard_map" not in text
+    assert text == _attend_jaxpr(four_chips, impl="xla", **case)
+
+
+@pytest.fixture(scope="module")
+def fsdp_steps(four_chips):
+    """starcoder2-3b at published widths, the benchmark's fsdp train step
+    (1 x 2,048 tokens a chip, full remat) compiled under ``"auto"`` and
+    ``"xla"``."""
+    mesh = four_chips
+    tcfg = TrainConfig(remat="full", layout="fsdp")
+    rep = NamedSharding(mesh, P())
+    out = {}
+    for impl in ("auto", "xla"):
+        model = build_model(get_config("starcoder2-3b").replace(
+            attn_impl=impl))
+        shard = param_shardings(model.abstract_params(), model.cfg, mesh,
+                                layout="fsdp")
+        state_shard = TrainState(params=shard, opt={"mu": shard}, step=rep)
+        state = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            jax.eval_shape(lambda k: init_state(model, tcfg, k),
+                           jax.random.key(0)), state_shard)
+        tokens = jax.ShapeDtypeStruct((4, 2048), I32)
+        bshard = batch_shardings({"tokens": tokens, "labels": tokens}, mesh,
+                                 "fsdp")
+        batch = {k: jax.ShapeDtypeStruct(tokens.shape, I32, sharding=sh)
+                 for k, sh in bshard.items()}
+        step = jax.jit(make_train_step(model, tcfg, param_shardings=shard),
+                       in_shardings=(state_shard, bshard, rep),
+                       out_shardings=(state_shard, None), donate_argnums=(0,))
+        with use_mesh(mesh, "fsdp"):
+            out[impl] = step.lower(state, batch, _on(rep, jax.ShapeDtypeStruct(
+                (), F32))).compile()
+    return out
+
+
+def _all_gathers(text):
+    return len(re.findall(r"= \S+ all-gather(?:-start)?\(", text))
+
+
+def _hbm(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_fsdp_step_runs_the_kernel_without_new_gathers(fsdp_steps):
+    auto, xla = (fsdp_steps[i].as_text() for i in ("auto", "xla"))
+    assert "tpu_custom_call" in auto and "tpu_custom_call" not in xla
+    assert _all_gathers(auto) == _all_gathers(xla) > 0
+
+
+def test_fsdp_step_with_the_kernel_fits_one_v5e(fsdp_steps):
+    auto, xla = _hbm(fsdp_steps["auto"]), _hbm(fsdp_steps["xla"])
+    assert auto < V5E_HBM_BYTES and auto <= xla, (auto, xla)
+
+
+def test_kernels_carry_the_attn_region_and_their_pass(fsdp_steps):
+    """``region_share.attn`` and ``pass_share.*`` read the kernels' op_name:
+    the forward, the recomputed forward and the backward each sit under
+    ``attn`` and say which pass they are."""
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in fsdp_steps["auto"].as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert names and all("/attn/kernel.flash_attention.pallas/" in n
+                         for n in names)
+    recompute = [n for n in names if "rematted_computation" in n]
+    bwd = [n for n in names if "transpose(" in n and n not in recompute]
+    fwd = [n for n in names if "transpose(" not in n]
+    assert len(fwd) == len(recompute) == 1 and len(bwd) == 2, names
