@@ -37,14 +37,20 @@ def _attn_flops(cfg: ModelConfig, T: float, kv_len: float, *,
                 causal: bool, window: int) -> float:
     """One attention layer: projections + scores + AV + out."""
     H, KV, Dh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.d_model
+    Dv = Dh
     proj = 2 * T * d * (H * Dh + 2 * KV * Dh) + 2 * T * H * Dh * d
+    if cfg.kv_lora_rank:          # latent attention: down, up, wider q/k
+        r, Dv = cfg.kv_lora_rank, cfg.v_head_dim or Dh
+        Dh = Dh + cfg.qk_rope_head_dim
+        proj = 2 * T * (d * H * Dh + d * (r + cfg.qk_rope_head_dim)
+                        + r * H * (cfg.head_dim + Dv) + H * Dv * d)
     if window and window > 0:
         seff = min(window, kv_len)
     elif causal:
         seff = (kv_len + 1) / 2
     else:
         seff = kv_len
-    sc = 2 * T * seff * H * Dh * 2                 # QK^T and PV
+    sc = 2 * T * seff * H * (Dh + Dv)              # QK^T and PV
     return proj + sc
 
 
@@ -56,12 +62,13 @@ def _mlp_flops(cfg: ModelConfig, T: float, d_ff: Optional[int] = None,
 
 
 def _moe_flops(cfg: ModelConfig, T: float) -> float:
-    d, f = cfg.d_model, cfg.d_ff
-    routed = 6 * T * d * f * cfg.top_k
+    """One expert layer; the routed part is the held experts' expected
+    share of the top-k."""
+    d, f = cfg.d_model, cfg.expert_ff
+    routed = 6 * T * d * f * cfg.top_k * cfg.held_experts / cfg.num_experts
     shared = 6 * T * d * f * cfg.num_shared_experts
     router = 2 * T * d * cfg.num_experts
-    dense = (6 * T * d * cfg.dense_ff
-             if cfg.dense_ff and not cfg.first_dense_layers else 0)
+    dense = 6 * T * d * cfg.dense_ff if cfg.dense_ff else 0
     return routed + shared + router + dense
 
 
@@ -104,7 +111,7 @@ def fwd_flops(cfg: ModelConfig, batch: int, seq: int, *,
         nd = cfg.first_dense_layers
         for _ in range(nd):
             total += _attn_flops(cfg, T, kv, causal=True, window=0)
-            total += _mlp_flops(cfg, T, d_ff=cfg.dense_ff, gated=True)
+            total += _mlp_flops(cfg, T, gated=True)
         for _ in range(cfg.num_layers - nd):
             total += _attn_flops(cfg, T, kv, causal=True, window=0)
             total += _moe_flops(cfg, T)

@@ -43,7 +43,9 @@ class ModelConfig:
     num_heads: int = 0
     num_kv_heads: int = 0
     head_dim: int = 0              # explicit; not always d_model // num_heads
-    d_ff: int = 0                  # dense FFN width (per-expert width for MoE)
+                                   # (the no-rotary q/k width under latent
+                                   # attention)
+    d_ff: int = 0                  # dense FFN width
     vocab_size: int = 0
     norm_eps: float = 1e-5
     qkv_bias: bool = False         # qwen2.5 uses attention QKV bias
@@ -56,14 +58,36 @@ class ModelConfig:
     sliding_window: int = 0        # 0 = every layer global
     global_every: int = 0          # e.g. 6 -> layers 5,11,... are global
 
+    # --- latent attention (MLA, deepseek-v3 / moonlight) --------------------
+    # kv_lora_rank > 0: keys and values come up from a normed latent of
+    # this rank; queries and keys carry head_dim no-rotary dims plus
+    # qk_rope_head_dim rotary ones (the rotary key shared by all heads);
+    # values are v_head_dim wide
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # --- MoE ---------------------------------------------------------------
     num_experts: int = 0
     top_k: int = 0
+    moe_d_ff: int = 0              # per-expert width; 0 = d_ff
     num_shared_experts: int = 0    # moonlight/deepseek-style always-on experts
-    dense_ff: int = 0              # width of dense-residual MLP (arctic) or
-                                   # dense first layer (moonshot)
-    first_dense_layers: int = 0    # moonshot: first k layers use dense FFN
+    dense_ff: int = 0              # width of arctic's dense-residual MLP
+    first_dense_layers: int = 0    # moonlight: first k layers use a dense
+                                   # FFN of width d_ff
     router_aux_coef: float = 0.001
+    # "softmax": the capacity paths (moe_impl), which drop tokens past a
+    # capacity. "sigmoid": the dropless held-expert layer, which chooses
+    # experts by sigmoid score plus a correction bias and weights them by
+    # the unbiased scores normalised over the top-k (deepseek-v3's
+    # noaux_tc with norm_topk_prob); the fields below apply to it
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0    # factor on the routed experts' weights
+    # the experts this chip holds: experts_held from first_held_expert
+    # (0 = all); the layer routes over all num_experts and computes the
+    # held experts' part of each token's sum
+    experts_held: int = 0
+    first_held_expert: int = 0
 
     # --- SSM / Mamba2 (zamba2) ---------------------------------------------
     ssm_state: int = 0             # N, state dimension per head
@@ -117,6 +141,14 @@ class ModelConfig:
         return self.num_heads * self.head_dim
 
     @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -138,19 +170,26 @@ class ModelConfig:
 
 
 def _moe_ffn_params(cfg: ModelConfig, active_only: bool) -> int:
-    """Per-layer FFN params for an MoE layer."""
-    e = cfg.top_k if active_only else cfg.num_experts
-    routed = e * 3 * cfg.d_model * cfg.d_ff
-    shared = cfg.num_shared_experts * 3 * cfg.d_model * cfg.d_ff
+    """Per-layer FFN params for an MoE layer (the held experts only)."""
+    e = cfg.top_k if active_only else cfg.held_experts
+    routed = e * 3 * cfg.d_model * cfg.expert_ff
+    shared = cfg.num_shared_experts * 3 * cfg.d_model * cfg.expert_ff
     router = cfg.d_model * cfg.num_experts
-    # arctic-style parallel dense branch; NOT moonshot's dense first layer
-    # (that one is counted by the first_dense_layers arm of _param_count)
-    dense = (3 * cfg.d_model * cfg.dense_ff
-             if cfg.dense_ff and not cfg.first_dense_layers else 0)
+    if cfg.router_scoring == "sigmoid":
+        router += cfg.num_experts                 # the correction bias
+    # arctic-style parallel dense branch
+    dense = 3 * cfg.d_model * cfg.dense_ff if cfg.dense_ff else 0
     return routed + shared + router + dense
 
 
 def _attn_params(cfg: ModelConfig) -> int:
+    if cfg.kv_lora_rank:
+        H, r, rope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        v = cfg.v_head_dim or cfg.head_dim
+        q = cfg.d_model * H * (cfg.head_dim + rope)
+        down = cfg.d_model * (r + rope) + r             # with the latent norm
+        up = r * H * (cfg.head_dim + v)
+        return q + down + up + H * v * cfg.d_model
     q = cfg.d_model * cfg.num_heads * cfg.head_dim
     kv = 2 * cfg.d_model * cfg.num_kv_heads * cfg.head_dim
     o = cfg.num_heads * cfg.head_dim * cfg.d_model
@@ -211,7 +250,7 @@ def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         if cfg.family == "moe" and i >= cfg.first_dense_layers:
             total += _moe_ffn_params(cfg, active_only)
         elif cfg.family == "moe":
-            total += 3 * cfg.d_model * cfg.dense_ff  # dense first layer(s)
+            total += 3 * cfg.d_model * cfg.d_ff      # dense first layer(s)
         else:
             total += _dense_ffn_params(cfg)
     return total
@@ -343,7 +382,7 @@ def list_archs() -> List[str]:
 
 ASSIGNED_ARCHS = (
     "zamba2-1.2b", "qwen2.5-14b", "granite-20b", "gemma3-27b",
-    "starcoder2-3b", "moonshot-v1-16b-a3b", "arctic-480b",
+    "starcoder2-3b", "moonlight-16b-a3b", "arctic-480b",
     "seamless-m4t-large-v2", "rwkv6-7b", "qwen2-vl-7b",
 )
 

@@ -10,7 +10,7 @@ from repro.configs import (  # noqa: F401
     granite_20b,
     gemma3_27b,
     starcoder2_3b,
-    moonshot_v1_16b_a3b,
+    moonlight_16b_a3b,
     arctic_480b,
     seamless_m4t_large_v2,
     rwkv6_7b,

@@ -39,7 +39,8 @@ from repro.core.cluster import SparseCluster
 from repro.models import modality
 from repro.models.builder import Model
 from repro.obs.profiling import region
-from repro.train.step import TrainState, cross_entropy, _token_weights
+from repro.train.step import (TrainState, _token_weights, aux_metrics,
+                              cross_entropy)
 
 PyTree = Any
 
@@ -76,8 +77,8 @@ def _make_row_weighted_loss(model: Model, tcfg: TrainConfig) -> Callable:
                 S = logits.shape[1]
                 w = _token_weights(cfg, flat, S) * row_w[:, None]
                 loss = cross_entropy(logits, flat["labels"], w)
-        total = loss + cfg.router_aux_coef * aux
-        return total, {"loss": loss, "aux": aux}
+        total = loss + cfg.router_aux_coef * aux["balance"]
+        return total, aux_metrics(loss, aux)
 
     return loss_fn
 

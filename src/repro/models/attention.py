@@ -1,4 +1,10 @@
-"""GQA/MQA/MHA attention: training/prefill (blockwise) and decode paths.
+"""GQA/MQA/MHA and latent attention: training/prefill (blockwise) and
+decode paths.
+
+Latent attention (MLA, ``cfg.kv_lora_rank > 0``) projects each token down
+to a normed latent and one rotary key shared by all heads, and up from
+the latent to each head's key and value (``project_qkv``); its q/k heads
+are wider than its v heads, and it has no decode cache yet.
 
 Full attention (``attend``) has two paths, chosen by ``cfg.attn_impl``:
 
@@ -11,8 +17,9 @@ Full attention (``attend``) has two paths, chosen by ``cfg.attn_impl``:
   shard both fall back to the XLA path.
 - the XLA path, which computes attention in query chunks
   (``cfg.attn_chunk``) so the materialized score block is
-  (B, kvh, g, Cq, Skv) instead of the full (B, H, S, S). ``"xla"`` forces
-  it.
+  (B, kvh, g, Cq, Skv) instead of the full (B, H, S, S), in the backward
+  pass too where there are more than two chunks (each is recomputed
+  there). ``"xla"`` forces it.
 
 Each path runs under its own named scope (``kernel.flash_attention.pallas``,
 ``kernel.attention.xla_scan``), so a trace tells which one ran. Decode
@@ -40,6 +47,8 @@ from repro.obs.profiling import annotate_span
 # ---------------------------------------------------------------------------
 
 def init_attention(kg: L.KeyGen, cfg: ModelConfig) -> Dict[str, L.Boxed]:
+    if cfg.kv_lora_rank:
+        return _init_latent(kg, cfg)
     d, H, KV, Dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = {
         "wq": L.param(kg, (d, H, Dh), ("embed", "heads", "head_dim")),
@@ -54,12 +63,56 @@ def init_attention(kg: L.KeyGen, cfg: ModelConfig) -> Dict[str, L.Boxed]:
     return p
 
 
+def _init_latent(kg: L.KeyGen, cfg: ModelConfig) -> Dict[str, L.Boxed]:
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    qk, v = cfg.head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim or cfg.head_dim
+    return {
+        "wq": L.param(kg, (d, H, qk), ("embed", "heads", "head_dim")),
+        # down to the latent and the shared rotary key
+        "wdkv": L.param(kg, (d, r + cfg.qk_rope_head_dim),
+                        ("embed", "kv_lora")),
+        "kv_norm": {"gamma": L.param(kg, (r,), ("kv_lora",), init="zeros")},
+        # up from the latent to each head's no-rotary key and value
+        "wk": L.param(kg, (r, H, cfg.head_dim),
+                      ("kv_lora", "heads", "head_dim")),
+        "wv": L.param(kg, (r, H, v), ("kv_lora", "heads", "head_dim")),
+        "wo": L.param(kg, (H, v, d), ("heads", "head_dim", "embed")),
+    }
+
+
+def _project_latent(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig,
+                    positions: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Latent attention's q, k (B,S,H,Dh+rope) and v (B,S,H,Dv):
+
+        q = x Wq = [q_n, rope(q_r)];   [c, k_r] = x Wdkv;   c = rms(c)
+        k = [c Wk, rope(k_r) for every head];   v = c Wv
+    """
+    dt = x.dtype
+    Dn, r = cfg.head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+    q = jnp.concatenate(
+        [q[..., :Dn], L.apply_rope(q[..., Dn:], positions, cfg.rope_theta)],
+        axis=-1)
+    ckv = x @ p["wdkv"].astype(dt)
+    c = L.rms_norm(ckv[..., :r], p["kv_norm"]["gamma"], cfg.norm_eps)
+    k_r = L.apply_rope(ckv[..., None, r:], positions, cfg.rope_theta)
+    k_n = jnp.einsum("bsr,rhk->bshk", c, p["wk"].astype(dt))
+    v = jnp.einsum("bsr,rhk->bshk", c, p["wv"].astype(dt))
+    k_r = jnp.broadcast_to(k_r, k_n.shape[:-1] + k_r.shape[-1:])
+    return q, jnp.concatenate([k_n, k_r], axis=-1), v
+
+
 def project_qkv(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig,
                 positions: Optional[jax.Array] = None,
                 mrope_positions: Optional[jax.Array] = None,
                 rope: bool = True) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: (B, S, d) -> q (B,S,H,Dh), k/v (B,S,KV,Dh), rotary applied."""
     dt = x.dtype
+    if cfg.kv_lora_rank:
+        if positions is None:
+            positions = jnp.arange(x.shape[1])[None, :]
+        return _project_latent(p, x, cfg, positions)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
@@ -115,9 +168,10 @@ def _chunk_attend(q_chunk: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
 
 
-def _use_flash(q: jax.Array, k: jax.Array, cfg: ModelConfig,
+def _use_flash(q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig,
                kv_len) -> bool:
-    if kv_len is not None:
+    # the kernel holds one head dim for q, k and v
+    if kv_len is not None or v.shape[-1] != q.shape[-1]:
         return False
     if cfg.attn_impl == "pallas":
         return True
@@ -128,8 +182,8 @@ def _use_flash(q: jax.Array, k: jax.Array, cfg: ModelConfig,
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig, *,
            causal: bool = True, window=0,
            kv_len: Optional[jax.Array] = None) -> jax.Array:
-    """Full attention. q: (B,S,H,Dh), k/v: (B,Skv,KV,Dh)."""
-    if _use_flash(q, k, cfg, kv_len):
+    """Full attention. q: (B,S,H,Dh), k: (B,Skv,KV,Dh), v: (B,Skv,KV,Dv)."""
+    if _use_flash(q, k, v, cfg, kv_len):
         out = flash.attention(q, k, v, causal=causal, window=window)
         if out is not None:
             return out
@@ -141,7 +195,7 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig, *,
 def _attend_xla(q, k, v, cfg: ModelConfig, *, causal, window, kv_len):
     """The q-chunked XLA path."""
     B, S, H, Dh = q.shape
-    KV = k.shape[2]
+    KV, Dv = k.shape[2], v.shape[-1]
     G = H // KV
     window = jnp.asarray(window, jnp.int32)
     qg = q.reshape(B, S, KV, G, Dh)
@@ -153,7 +207,7 @@ def _attend_xla(q, k, v, cfg: ModelConfig, *, causal, window, kv_len):
     if n == 1:
         out = _chunk_attend(qg, k, v, jnp.asarray(0), causal=causal,
                             window=window, kv_len=kv_len)
-        return out.reshape(B, S, H, Dh)
+        return out.reshape(B, S, H, Dv)
 
     qcs = qg.reshape(B, n, C, KV, G, Dh).transpose(1, 0, 2, 3, 4, 5)
     offs = jnp.arange(n) * C
@@ -163,9 +217,15 @@ def _attend_xla(q, k, v, cfg: ModelConfig, *, causal, window, kv_len):
         return None, _chunk_attend(qc, k, v, off, causal=causal,
                                    window=window, kv_len=kv_len)
 
+    if n > 2:
+        # the backward pass would hold every chunk's scores, n times the
+        # (B, KV, G, C, S) block the chunking bounds: make each chunk's
+        # again there instead. Up to two chunks, holding them costs at
+        # most twice a block and saves a pass over the scores.
+        body = jax.checkpoint(body, prevent_cse=False)
     _, outs = jax.lax.scan(body, None, (qcs, offs))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, KV, G, Dh)
-    return out.reshape(B, S, H, Dh)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, KV, G, Dv)
+    return out.reshape(B, S, H, Dv)
 
 
 # ---------------------------------------------------------------------------

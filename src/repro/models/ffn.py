@@ -1,14 +1,28 @@
 """Feed-forward layers: dense (SwiGLU / GeLU-4x) and mixture-of-experts.
 
-The MoE uses *row-local capacity dispatch*: top-k routing, tokens packed into
-per-expert capacity buffers independently within each batch row. Keeping the
-scatter row-local means the dispatch never moves tokens across the ``data``
-mesh axis — only the expert-sharded einsum communicates over ``model`` —
-which is the property that makes the layer GSPMD-shardable at 512 chips.
-FLOPs are proportional to *active* (top-k) compute, not ``num_experts``.
+Two kinds of expert layer, chosen by ``cfg.router_scoring``:
 
-Over-capacity tokens are dropped (Switch-style, capacity_factor 1.25); the
-residual connection passes them through unchanged.
+- ``"sigmoid"``: the dropless held-expert layer (``apply_held_moe``,
+  deepseek-v3's routing). It is told which experts it holds
+  (``experts_held`` from ``first_held_expert``), routes every token over
+  all ``num_experts``, and computes its held experts' part of each
+  token's sum with grouped matmuls over the assignments sorted by
+  expert: no capacity, so no token is dropped. On a chip of an
+  expert-parallel deployment that is the chip's share of the layer.
+- ``"softmax"``: *row-local capacity dispatch* (``moe_impl`` gspmd, ep,
+  a2a): top-k routing, tokens packed into per-expert capacity buffers
+  independently within each batch row. Keeping the scatter row-local
+  means the dispatch never moves tokens across the ``data`` mesh axis —
+  only the expert-sharded einsum communicates over ``model``. FLOPs are
+  proportional to *active* (top-k) compute, not ``num_experts``.
+  Over-capacity tokens are dropped (Switch-style, capacity_factor 1.25);
+  the residual connection passes them through unchanged.
+
+Every expert layer returns ``(out, aux)``; ``aux`` holds the router's
+balance loss (``"balance"``), and the held-expert layer adds
+``"moe_held_share"`` (the share of token-expert assignments that landed
+on held experts) and ``"moe_load_max"`` (the most rows one held expert
+got, over the held experts' mean).
 """
 from __future__ import annotations
 
@@ -20,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models import layers as L
+from repro.obs.profiling import region
 
 CAPACITY_FACTOR = 1.25
 
@@ -59,16 +74,24 @@ def moe_capacity(seq_len: int, cfg: ModelConfig) -> int:
 
 
 def init_moe(kg: L.KeyGen, cfg: ModelConfig) -> Dict[str, L.Boxed]:
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+    d, f, E = cfg.d_model, cfg.expert_ff, cfg.num_experts
+    Eh = cfg.held_experts
+    sigmoid = cfg.router_scoring == "sigmoid"
     p = {
-        "router": L.param(kg, (d, E), ("embed", "experts"), scale=0.02),
-        "wi": L.param(kg, (E, d, f), ("experts", "embed", "ff")),
-        "wg": L.param(kg, (E, d, f), ("experts", "embed", "ff")),
-        "wo": L.param(kg, (E, f, d), ("experts", "ff", "embed")),
+        # the sigmoid router runs in float32: not cast to cfg.dtype
+        "router": L.param(kg, (d, E), ("embed", "experts"), scale=0.02,
+                          cast=not sigmoid),
+        "wi": L.param(kg, (Eh, d, f), ("experts", "embed", "ff")),
+        "wg": L.param(kg, (Eh, d, f), ("experts", "embed", "ff")),
+        "wo": L.param(kg, (Eh, f, d), ("experts", "ff", "embed")),
     }
+    if sigmoid:
+        # chooses experts, does not weight them; set by a load-based
+        # update in the source's optimizer, not by the gradient
+        p["router_bias"] = L.param(kg, (E,), ("experts",), init="zeros")
     if cfg.num_shared_experts:
         p["shared"] = init_mlp(kg, d, cfg.num_shared_experts * f, gated=True)
-    if cfg.dense_ff and not cfg.first_dense_layers:
+    if cfg.dense_ff:
         # arctic-style dense residual branch, parallel to the routed experts
         p["dense"] = init_mlp(kg, d, cfg.dense_ff, gated=True)
     return p
@@ -97,9 +120,54 @@ def _route_row(x: jax.Array, probs: jax.Array, cfg: ModelConfig, capacity: int
     return buf, slot, keep, flat_w
 
 
+# the held-expert layer's counters, beside the balance loss every expert
+# layer returns
+HELD_AUX = ("moe_held_share", "moe_load_max")
+
+
+def aux_zeros(cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """The aux dict ``apply_moe`` returns for ``cfg``, at zero: where the
+    expert layers' aux is folded in with ``merge_aux``."""
+    names = ("balance",) + (HELD_AUX if cfg.router_scoring == "sigmoid"
+                            else ())
+    return {k: jnp.zeros((), jnp.float32) for k in names}
+
+
+def merge_aux(acc: Dict[str, jax.Array], aux: Dict[str, jax.Array]
+              ) -> Dict[str, jax.Array]:
+    """Fold one expert layer's aux, or one microbatch's step metrics, into
+    the running dict: ``moe_load_max`` is a max, every other entry a sum."""
+    return {k: jnp.maximum(v, aux[k]) if k == "moe_load_max" else v + aux[k]
+            for k, v in acc.items()}
+
+
+def layers_aux(aux: Dict[str, jax.Array], layers: int
+               ) -> Dict[str, jax.Array]:
+    """The aux merged over ``layers`` expert layers: the balance loss is
+    their sum, ``moe_held_share`` their mean, ``moe_load_max`` their max."""
+    if "moe_held_share" in aux:
+        aux = dict(aux, moe_held_share=aux["moe_held_share"] / layers)
+    return aux
+
+
+def mean_aux(aux: Dict[str, jax.Array], n: int) -> Dict[str, jax.Array]:
+    """The step metrics merged over ``n`` microbatches: each sum becomes
+    a mean; ``moe_load_max`` stays their max."""
+    return {k: v if k == "moe_load_max" else v / n for k, v in aux.items()}
+
+
 def apply_moe(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (out, aux_loss). Row-local capacity dispatch."""
+              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x: (B, S, D) -> (out, aux). The held-expert layer for a sigmoid
+    router, else row-local capacity dispatch."""
+    if cfg.router_scoring == "sigmoid":
+        return apply_held_moe(p, x, cfg)
+    out, aux = _apply_capacity_moe(p, x, cfg)
+    return out, {"balance": aux}
+
+
+def _apply_capacity_moe(p: Dict[str, jax.Array], x: jax.Array,
+                        cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     if cfg.moe_impl == "ep" and x.shape[1] > 1:
         out, aux = _apply_moe_ep(p, x, cfg)
         if out is not None:
@@ -138,6 +206,101 @@ def apply_moe(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig
     # Switch-style load-balance aux: E * sum_e( frac_tokens_e * mean_prob_e )
     sel = jax.nn.one_hot(jnp.argmax(logits, -1), E, dtype=jnp.float32)
     aux = E * jnp.mean(jnp.mean(sel, axis=(0, 1)) * jnp.mean(probs, axis=(0, 1)))
+    return out, aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless held-expert MoE (sigmoid routing with a correction bias)
+# ---------------------------------------------------------------------------
+
+def route_sigmoid(p: Dict[str, jax.Array], x2: jax.Array, cfg: ModelConfig
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x2: (T, D) -> (expert ids (T, k), weights (T, k), scores (T, E)),
+    in float32: sigmoid scores, plus the bias to choose the top k, the
+    unbiased scores (normalised over the k, times ``routed_scaling``) to
+    weight them."""
+    logits = jnp.dot(x2.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["router_bias"].astype(jnp.float32),
+                           cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.routed_scaling, scores
+
+
+def seq_balance_loss(idx: jax.Array, scores: jax.Array, E: int
+                     ) -> jax.Array:
+    """DeepSeek-V3's sequence-wise balance loss, idx (B, S, k), scores
+    (B, S, E): the mean over sequences of sum_e f_e P_e, with f_e the
+    expert's share of the sequence's assignments times E, and P_e its
+    mean score normalised over all experts."""
+    S, k = idx.shape[1], idx.shape[2]
+    count = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(axis=(1, 2))
+    f = count * (E / (S * k))
+    P = (scores / scores.sum(-1, keepdims=True)).mean(axis=1)
+    return jnp.mean(jnp.sum(f * P, axis=-1))
+
+
+def held_experts(p: Dict[str, jax.Array], x2: jax.Array, idx: jax.Array,
+                 w: jax.Array, cfg: ModelConfig
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of each token's weighted sum, x2 (T, D),
+    idx/w (T, k) -> ((T, D), rows per held expert). Nothing is dropped:
+    every assignment to a held expert is computed."""
+    T, D = x2.shape
+    k = idx.shape[1]
+    held = p["wi"].shape[0]
+    dt = x2.dtype
+    n = T * k
+    rows = T * min(k, held)           # at most min(k, held) held per token
+    with region("router"):
+        # counting sort of the assignments: held experts first, by expert
+        e = idx.reshape(n) - cfg.first_held_expert
+        key = jnp.where((e >= 0) & (e < held), e, held)
+        onehot = jax.nn.one_hot(key, held + 1, dtype=jnp.int32)
+        rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), key[:, None],
+                                   axis=1)[:, 0] - 1
+        counts = onehot.sum(axis=0)
+        dest = (jnp.cumsum(counts) - counts)[key] + rank   # slot -> row
+        src = jnp.zeros((n,), jnp.int32).at[dest].set(
+            jnp.arange(n, dtype=jnp.int32))[:rows]         # row -> slot
+        sizes = counts[:held]
+        live = (jnp.arange(rows) < sizes.sum())[:, None]
+    with region("experts"):
+        xs = jnp.where(live, x2[src // k], 0)
+        h = jax.lax.ragged_dot(xs, p["wi"].astype(dt), sizes)
+        g = jax.lax.ragged_dot(xs, p["wg"].astype(dt), sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * h, p["wo"].astype(dt), sizes)
+        y = jnp.where(live, y, 0)
+        if rows < n:
+            y = jnp.concatenate([y, jnp.zeros((n - rows, D), dt)])
+        y = y[dest].reshape(T, k, D)
+        out = jnp.einsum("tkd,tk->td", y, w.astype(dt),
+                         preferred_element_type=jnp.float32)
+    return out.astype(dt), sizes
+
+
+def apply_held_moe(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x: (B, S, D) -> (held experts' part + shared experts, aux)."""
+    B, S, D = x.shape
+    x2 = x.reshape(B * S, D)
+    with region("router"):
+        idx, w, scores = route_sigmoid(p, x2, cfg)
+    out, sizes = held_experts(p, x2, idx, w, cfg)
+    out = out.reshape(B, S, D)
+    if "shared" in p:
+        out = out + apply_mlp(p["shared"], x)
+    rows = sizes.sum().astype(jnp.float32)
+    aux = {
+        "balance": seq_balance_loss(idx.reshape(B, S, -1),
+                                    scores.reshape(B, S, -1),
+                                    cfg.num_experts),
+        "moe_held_share": rows / idx.size,
+        "moe_load_max": jnp.max(sizes) * sizes.shape[0] / jnp.maximum(rows,
+                                                                      1.0),
+    }
     return out, aux
 
 

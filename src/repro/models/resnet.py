@@ -87,7 +87,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> PyTree:
 
 def forward(params: PyTree, cfg: ModelConfig, batch: Dict[str, jax.Array],
             remat: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """images (B, H, W, 3) -> (logits (B, num_classes), aux=0)."""
+    """images (B, H, W, 3) -> (logits (B, num_classes), aux): no balance
+    loss, ``aux["balance"]`` is 0."""
     x = batch["images"].astype(jnp.dtype(cfg.dtype))
     x = conv2d(x, params["stem"])
     x = jax.nn.relu(group_norm(x, params["stem_gn"]["gamma"],
@@ -107,4 +108,4 @@ def forward(params: PyTree, cfg: ModelConfig, batch: Dict[str, jax.Array],
             x = jax.nn.relu(h + sc)
     x = x.mean(axis=(1, 2))
     logits = x @ params["fc_w"].astype(x.dtype) + params["fc_b"].astype(x.dtype)
-    return logits, jnp.zeros((), jnp.float32)
+    return logits, {"balance": jnp.zeros((), jnp.float32)}

@@ -4,8 +4,9 @@ Families
 --------
 - ``dense``  : decoder-only (GQA/MQA/MHA), optional gemma3-style 5:1
                local:global sliding-window pattern (per-layer runtime window).
-- ``moe``    : decoder-only with MoE FFN; supports moonshot's dense first
-               layer(s) and arctic's parallel dense-residual branch.
+- ``moe``    : decoder-only with MoE FFN; supports moonlight's dense first
+               layer(s) and latent attention, and arctic's parallel
+               dense-residual branch.
 - ``hybrid`` : zamba2 — Mamba2 backbone with a *weight-tied shared* attention
                block invoked every ``shared_attn_every`` layers.
 - ``ssm``    : rwkv6 — attention-free time-mix / channel-mix.
@@ -21,7 +22,7 @@ block cadence) expressed as scanned runtime scalars or nested scans.
 
 Public entry points (used by train/serve/dryrun):
     init_params(cfg, key)                      -> Boxed pytree
-    forward(params, cfg, batch)                -> logits (train / prefill)
+    forward(params, cfg, batch)                -> (logits, aux) (train / prefill)
     init_decode_cache(cfg, batch, max_len)     -> cache pytree
     decode_step(params, cfg, cache, batch)     -> (logits, new cache)
 """
@@ -92,6 +93,7 @@ def _mlp_block(lp, x, cfg: ModelConfig):
 
 
 def _moe_block(lp, x, cfg: ModelConfig):
+    """(x + the expert layer's output, the layer's aux dict)."""
     with region("mlp"):
         h = L.rms_norm(x, lp["ln2"]["gamma"], cfg.norm_eps)
         out, aux = F.apply_moe(lp["moe"], h, cfg)
@@ -122,12 +124,12 @@ def _init_moe_layer(kg: L.KeyGen, cfg: ModelConfig) -> Dict[str, PyTree]:
 
 
 def _init_moe_dense_layer(kg: L.KeyGen, cfg: ModelConfig) -> Dict[str, PyTree]:
-    """moonshot: first layer(s) use a plain dense MLP of width dense_ff."""
+    """moonlight: first layer(s) use a SwiGLU MLP of width d_ff."""
     return {
         "ln1": L.init_rms(kg, cfg.d_model),
         "attn": A.init_attention(kg, cfg),
         "ln2": L.init_rms(kg, cfg.d_model),
-        "mlp": F.init_mlp(kg, cfg.d_model, cfg.dense_ff, True),
+        "mlp": F.init_mlp(kg, cfg.d_model, cfg.d_ff, True),
     }
 
 
@@ -240,7 +242,6 @@ def _dense_trunk(params, cfg: ModelConfig, x, positions, mrope_positions=None,
 
 
 def _moe_trunk(params, cfg: ModelConfig, x, positions, remat=True):
-    aux_total = jnp.zeros((), jnp.float32)
     if "dense_layers" in params:
         def dbody(h, lp):
             h = _attn_block(lp, h, cfg, window=jnp.int32(0), positions=positions)
@@ -252,10 +253,11 @@ def _moe_trunk(params, cfg: ModelConfig, x, positions, remat=True):
         h, aux = carry
         h = _attn_block(lp, h, cfg, window=jnp.int32(0), positions=positions)
         h, a = _moe_block(lp, h, cfg)
-        return (h, aux + a), None
+        return (h, F.merge_aux(aux, a)), None
 
-    (x, aux_total), _ = _scan(body, (x, aux_total), params["layers"], cfg, remat)
-    return x, aux_total
+    (x, aux), _ = _scan(body, (x, F.aux_zeros(cfg)), params["layers"], cfg,
+                        remat)
+    return x, F.layers_aux(aux, params["layers"]["ln1"]["gamma"].shape[0])
 
 
 def _shared_block(sp, x, cfg: ModelConfig, positions):
@@ -325,8 +327,11 @@ def _encdec_trunk(params, cfg: ModelConfig, enc_x, dec_x, positions, remat=True)
 
 
 def forward(params: PyTree, cfg: ModelConfig, batch: Dict[str, jax.Array],
-            remat: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence forward. Returns (logits, moe_aux_loss).
+            remat: bool = True) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Full-sequence forward. Returns (logits, aux): ``aux["balance"]`` is
+    the expert layers' summed balance loss (0 without experts); the
+    held-expert layer adds ``moe_held_share`` (mean over the expert
+    layers) and ``moe_load_max`` (max over them), see ``models/ffn.py``.
 
     ``batch`` keys by family:
       dense/moe/ssm : tokens (B, S)
@@ -336,7 +341,7 @@ def forward(params: PyTree, cfg: ModelConfig, batch: Dict[str, jax.Array],
       hybrid        : tokens (B, S)
     """
     dt = _dtype(cfg)
-    aux = jnp.zeros((), jnp.float32)
+    aux = {"balance": jnp.zeros((), jnp.float32)}
     fam = cfg.family
 
     if fam == "encdec":
@@ -382,10 +387,19 @@ def forward(params: PyTree, cfg: ModelConfig, batch: Dict[str, jax.Array],
 # Decode: one new token against per-layer caches
 # ---------------------------------------------------------------------------
 
+def _no_latent_cache(cfg: ModelConfig) -> None:
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.name}: decoding latent attention needs a latent KV "
+            "cache (the normed latent and the shared rotary key per "
+            "position), which this model does not have yet")
+
+
 def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int,
                       enc_len: int = 0) -> PyTree:
     """Cache pytree for ``decode_step``. Family-dependent layout; every
     leaf's leading axis is the stacked layer dimension so decode scans it."""
+    _no_latent_cache(cfg)
     dt = _dtype(cfg)
     fam = cfg.family
     KV, Dh = cfg.num_kv_heads, cfg.head_dim
@@ -451,6 +465,7 @@ def init_paged_decode_cache(cfg: ModelConfig, batch: int, max_len: int, *,
     ``page_table`` leaf ``(batch, ceil(max_len/page_size))``. Recurrent
     per-row state (SSM/RWKV/Mamba conv+state) carries no length axis and
     stays dense — paging governs only what grows with tokens."""
+    _no_latent_cache(cfg)
     dt = _dtype(cfg)
     fam = cfg.family
     KV, Dh = cfg.num_kv_heads, cfg.head_dim
@@ -514,6 +529,7 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, cache: PyTree,
     frozen. Recurrent per-row leaves still compute for masked rows; the
     paged prefill wrapper selects them back, and the engine resets rows
     at admission, exactly like the dense path."""
+    _no_latent_cache(cfg)
     dt = _dtype(cfg)
     fam = cfg.family
     pos = cache["pos"]
@@ -651,6 +667,7 @@ def decode_step(params: PyTree, cfg: ModelConfig, cache: PyTree,
     Returns (logits (B, 1, V), new cache). ``cache['pos']`` is the write
     index for this step (the number of tokens already in the cache).
     """
+    _no_latent_cache(cfg)
     dt = _dtype(cfg)
     fam = cfg.family
     pos = cache["pos"]

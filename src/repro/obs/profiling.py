@@ -27,6 +27,9 @@ REGIONS = {
     "layers": "the trunk's scan over stacked layers",
     "attn": "norm, q/k/v projections, attention and out-projection",
     "mlp": "norm and the dense or expert MLP",
+    # inside mlp, the held-expert layer (models/ffn.py)
+    "router": "gate logits, scores, top-k, weights, the sort and its inverse",
+    "experts": "held experts' matmuls, activation and weighted combine",
     "head": "final norm, unembedding and the loss",
     "update": "gradient cast and clip, schedule, optimizer and apply",
 }

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig, TrainConfig
 from repro.models import modality
 from repro.models.builder import Model
+from repro.models.ffn import aux_zeros, mean_aux, merge_aux
 from repro.obs.profiling import region
 from repro.optim import make_optimizer, make_schedule
 from repro.optim.optimizers import clip_by_global_norm
@@ -88,8 +89,17 @@ def loss_fn(model: Model, params: PyTree, batch: Dict[str, jax.Array],
             S = logits.shape[1]
             w = _token_weights(cfg, batch, S)
             loss = cross_entropy(logits, batch["labels"], w)
-    total = loss + cfg.router_aux_coef * aux
-    return total, {"loss": loss, "aux": aux}
+    total = loss + cfg.router_aux_coef * aux["balance"]
+    return total, aux_metrics(loss, aux)
+
+
+def aux_metrics(loss: jax.Array, aux: Dict[str, jax.Array]
+                ) -> Dict[str, jax.Array]:
+    """The step's metrics from its loss and the model's aux: ``aux`` is
+    the balance loss, and the held-expert layer's ``moe_held_share`` and
+    ``moe_load_max`` pass through under their own names."""
+    return {"loss": loss, "aux": aux["balance"],
+            **{k: v for k, v in aux.items() if k != "balance"}}
 
 
 # ---------------------------------------------------------------------------
@@ -159,14 +169,14 @@ def make_train_step(model: Model, tcfg: TrainConfig, param_shardings=None,
                 g_acc, m_acc = carry
                 g, m = grads_of(state.params, mbatch)
                 g_acc = jax.tree.map(lambda a, b: a + b / k, g_acc, g)
-                m_acc = jax.tree.map(lambda a, b: a + b / k, m_acc, m)
-                return (g_acc, m_acc), None
+                return (g_acc, merge_aux(m_acc, m)), None
 
             split = jax.tree.map(
                 lambda x: x.reshape((k, x.shape[0] // k) + x.shape[1:]), batch)
             zeros_g = jax.tree.map(jnp.zeros_like, state.params)
-            zeros_m = {"loss": jnp.float32(0), "aux": jnp.float32(0)}
+            zeros_m = aux_metrics(jnp.float32(0), aux_zeros(model.cfg))
             (grads, metrics), _ = jax.lax.scan(mb, (zeros_g, zeros_m), split)
+            metrics = mean_aux(metrics, k)
         else:
             grads, metrics = grads_of(state.params, batch)
 

@@ -3,7 +3,8 @@
 The thin orchestration layer over ``make_train_step``: restore-on-start
 (master-less checkpoint scan), periodic saves, revocation-warning fast
 saves, and observability via an ``obs.Recorder`` (per-step spans plus the
-``steps_total``/``step_latency_ms`` series; the legacy ``metrics_log``
+``steps_total``/``step_latency_ms`` series, and the held-expert layer's
+``moe_held_share``/``moe_load_max`` gauges; the legacy ``metrics_log``
 list is kept as a plain-Python view of the same numbers). Elastic
 membership is layered on top by ``core.elastic.ElasticRuntime``; this
 class is the static-cluster loop the paper starts from (1/2/4/8 fixed
@@ -29,6 +30,7 @@ from repro.config import TrainConfig
 from repro.core.checkpoint import CheckpointManager
 from repro.data.pipeline import ShardedDataset
 from repro.models.builder import Model
+from repro.models.ffn import HELD_AUX
 from repro.train.step import TrainState, init_state, make_train_step
 
 PyTree = Any
@@ -90,6 +92,9 @@ class Trainer:
                             loss=float(m["loss"]), mode="static")
                 rec.metrics.counter("steps_total", mode="static").inc()
                 rec.metrics.histogram("step_latency_ms").observe(dt * 1e3)
+                for name in HELD_AUX:
+                    if name in m:
+                        rec.metrics.gauge(name).set(float(m[name]))
             if on_step is not None:
                 on_step(step, m)
             if (step + 1) % self.log_every == 0 or step == start:

@@ -85,3 +85,28 @@ def test_forced_kernel_keeps_the_scan_for_kv_len():
     assert "pallas_call" not in text
     assert "pallas_call" in _jaxpr(
         lambda q, k, v: A.attend(q, k, v, _cfg("pallas")), q, k, k)
+
+
+@pytest.mark.parametrize("chunks", [2, 4])
+def test_chunked_scan_matches_one_block_with_unequal_v(chunks):
+    """The q-chunk scan, with v narrower than q/k as latent attention has
+    it, gives one block's output and gradients, whether each chunk's
+    scores are held for the backward pass (2 chunks) or made again there
+    (more than 2)."""
+    S = 64
+    ks = jax.random.split(jax.random.key(0), 3)
+    q = jax.random.normal(ks[0], (2, S, 4, 24), F32)
+    k = jax.random.normal(ks[1], (2, S, 4, 24), F32)
+    v = jax.random.normal(ks[2], (2, S, 4, 16), F32)
+
+    def loss(cfg):
+        return lambda q, k, v: jnp.sum(jnp.sin(A.attend(q, k, v, cfg)))
+
+    one = _cfg("xla").replace(attn_chunk=S)
+    many = one.replace(attn_chunk=S // chunks)
+    want = jax.value_and_grad(loss(one), argnums=(0, 1, 2))(q, k, v)
+    got = jax.value_and_grad(loss(many), argnums=(0, 1, 2))(q, k, v)
+    assert ("remat" in _jaxpr(loss(many), q, k, v)) == (
+        chunks > 2)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert jnp.allclose(a, b, rtol=1e-5, atol=1e-5)

@@ -1,5 +1,6 @@
-"""MoE execution paths: gspmd vs shard_map EP vs a2a EP equivalence, and
-the layout/sharding rules added by the §Perf hillclimb."""
+"""MoE execution paths: gspmd vs shard_map EP vs a2a EP equivalence for
+the softmax-routed capacity layer, and the layout/sharding rules added by
+the §Perf hillclimb."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -12,7 +13,19 @@ from repro.models import layers as L
 from repro.models.builder import build_model
 from repro.sharding import param_spec, use_mesh
 
-ARCHS = ("moonshot-v1-16b-a3b", "arctic-480b")
+def softmax_moe(arch):
+    """The capacity layer's configs: arctic-480b reduced, and a variant of
+    it with a shared expert and a dense first layer in place of arctic's
+    parallel dense branch."""
+    cfg = get_config("arctic-480b", reduced=True)
+    if arch == "shared-dense-first":
+        cfg = cfg.replace(name=arch, num_layers=3, num_shared_experts=1,
+                          first_dense_layers=1, dense_ff=0, d_ff=128,
+                          moe_d_ff=64)
+    return cfg
+
+
+ARCHS = ("shared-dense-first", "arctic-480b")
 
 
 @pytest.fixture(scope="module")
@@ -22,7 +35,7 @@ def mesh():
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_ep_matches_gspmd(arch, mesh):
-    cfg = get_config(arch, reduced=True).replace(dtype="float32")
+    cfg = softmax_moe(arch).replace(dtype="float32")
     mx = build_model(cfg)
     mp = build_model(cfg.replace(moe_impl="ep"))
     params = L.unbox(mx.init(jax.random.key(0)))
@@ -33,13 +46,13 @@ def test_ep_matches_gspmd(arch, mesh):
         op, ap = jax.jit(lambda p, b: mp.apply(p, b, remat=False))(params,
                                                                    batch)
     assert float(jnp.max(jnp.abs(ox - op))) < 1e-4
-    assert abs(float(ax) - float(ap)) < 1e-5
+    assert abs(float(ax["balance"]) - float(ap["balance"])) < 1e-5
 
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_a2a_matches_gspmd(arch, mesh):
     # B=1 so the per-rank token pool equals the gspmd per-row pool exactly
-    cfg = get_config(arch, reduced=True).replace(dtype="float32")
+    cfg = softmax_moe(arch).replace(dtype="float32")
     mx = build_model(cfg)
     ma = build_model(cfg.replace(moe_impl="a2a"))
     params = L.unbox(mx.init(jax.random.key(1)))
@@ -54,8 +67,7 @@ def test_a2a_matches_gspmd(arch, mesh):
 
 def test_a2a_falls_back_outside_mesh():
     """Without a mesh the a2a config must still run (gspmd fallback)."""
-    cfg = get_config("moonshot-v1-16b-a3b",
-                     reduced=True).replace(moe_impl="a2a")
+    cfg = softmax_moe("shared-dense-first").replace(moe_impl="a2a")
     model = build_model(cfg)
     params = L.unbox(model.init(jax.random.key(0)))
     batch = make_batch(cfg, 2, 16)
@@ -64,15 +76,15 @@ def test_a2a_falls_back_outside_mesh():
 
 
 def test_a2a_is_differentiable(mesh):
-    cfg = get_config("moonshot-v1-16b-a3b",
-                     reduced=True).replace(dtype="float32", moe_impl="a2a")
+    cfg = softmax_moe("shared-dense-first").replace(dtype="float32",
+                                                    moe_impl="a2a")
     model = build_model(cfg)
     params = L.unbox(model.init(jax.random.key(0)))
     batch = make_batch(cfg, 1, 16)
 
     def loss(p):
         logits, aux = model.apply(p, batch, remat=False)
-        return jnp.mean(logits.astype(jnp.float32) ** 2) + aux
+        return jnp.mean(logits.astype(jnp.float32) ** 2) + aux["balance"]
 
     with use_mesh(mesh, "zero1"):
         g = jax.jit(jax.grad(loss))(params)
@@ -110,7 +122,7 @@ def test_fsdp_layout_skips_layer_stacked_dim():
 def test_zero1_expert_weights_stay_ep_sharded():
     """Experts: 'model' keeps EP; largest other dim FSDPs over 'data'."""
     m = _mesh_like(16, 16)
-    cfg = get_config("moonshot-v1-16b-a3b")
+    cfg = get_config("moonlight-16b-a3b")
     spec = param_spec(("experts", "embed", "ff"), cfg, m, (64, 2048, 1408),
                       layout="zero1")
     assert spec[0] == "model"

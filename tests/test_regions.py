@@ -26,9 +26,12 @@ TCFG = TrainConfig(
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-@pytest.fixture(scope="module")
-def op_names():
-    model = build_model(CFG)
+MOE_CFG = get_config("moonlight-16b-a3b", reduced=True)
+MOE_REGIONS = {"router", "experts"}     # only an expert layer names them
+
+
+def _step_op_names(cfg):
+    model = build_model(cfg)
     state = jax.eval_shape(lambda k: init_state(model, TCFG, k),
                            jax.random.key(0))
     batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
@@ -38,15 +41,40 @@ def op_names():
     return OP_NAME.findall(text)
 
 
+@pytest.fixture(scope="module")
+def op_names():
+    return _step_op_names(CFG)
+
+
+@pytest.fixture(scope="module")
+def moe_op_names():
+    return _step_op_names(MOE_CFG)
+
+
 def scopes(path):
     # "transpose(jvp(layers))" -> "layers"
     return {re.sub(r"^(?:[\w.]+\()*|\)*$", "", part)
             for part in path.split("/")}
 
 
-def test_compiled_step_names_every_region(op_names):
+def test_compiled_step_names_every_region(op_names, moe_op_names):
     named = set().union(*(scopes(p) for p in op_names))
-    assert set(REGIONS) <= named
+    assert set(REGIONS) - MOE_REGIONS <= named
+    assert not MOE_REGIONS & named
+    assert set(REGIONS) <= set().union(*(scopes(p) for p in moe_op_names))
+
+
+@pytest.mark.parametrize("inner", sorted(MOE_REGIONS))
+def test_expert_regions_sit_in_mlp_in_every_pass(moe_op_names, inner):
+    """``region_share.router``/``.experts`` read ops inside ``mlp``, in
+    the forward, the recomputed forward and the backward pass."""
+    paths = [p for p in moe_op_names if f"/{inner}/" in p]
+    assert paths and all("mlp" in scopes(p.split(f"/{inner}/")[0])
+                         for p in paths)
+    assert any("rematted_computation" in p for p in paths)
+    assert any("transpose(" in p for p in paths)
+    assert any("transpose(" not in p and "rematted_computation" not in p
+               for p in paths)
 
 
 @pytest.mark.parametrize("mark", ["rematted_computation", "transpose("])

@@ -27,8 +27,12 @@ def test_remesh_cache_compiles_once_per_size():
 
 
 def test_serving_moe_arch():
-    """Continuous batching through a MoE model (router state per token)."""
-    cfg = get_config("moonshot-v1-16b-a3b", reduced=True)
+    """Continuous batching through a MoE model (router state per token):
+    the softmax-routed capacity layer with a shared expert and a dense
+    first layer."""
+    cfg = get_config("arctic-480b", reduced=True).replace(
+        num_layers=3, num_shared_experts=1, first_dense_layers=1, dense_ff=0,
+        d_ff=128, moe_d_ff=64)
     model = build_model(cfg)
     params = L.unbox(model.init(jax.random.key(0)))
     eng = ServeEngine(model, params, max_batch=2, max_len=24)

@@ -35,7 +35,7 @@ def test_forward_shapes_finite(built, arch):
     exp_s = batch["labels"].shape[1] if "labels" in batch else S
     assert logits.shape == (B, exp_s, cfg.vocab_size)
     assert bool(jnp.all(jnp.isfinite(logits.astype(jnp.float32))))
-    assert bool(jnp.isfinite(aux))
+    assert all(bool(jnp.isfinite(v)) for v in aux.values())
 
 
 def test_resnet_forward(built):
@@ -64,7 +64,12 @@ def test_train_step(built, arch):
     assert changed
 
 
-@pytest.mark.parametrize("arch", ASSIGNED_ARCHS)
+# latent attention has no decode cache yet (test_latent_decode_is_refused)
+DECODE_ARCHS = tuple(a for a in ASSIGNED_ARCHS
+                     if not get_config(a, reduced=True).kv_lora_rank)
+
+
+@pytest.mark.parametrize("arch", DECODE_ARCHS)
 def test_decode_step(built, arch):
     cfg, model, params = built[arch]
     if cfg.family == "encdec":
@@ -98,3 +103,19 @@ def test_prefill_decode_consistency(built, arch):
                                               {"tokens": toks[:, i:i + 1]})
     assert jnp.allclose(logits[:, -1], last[:, -1], atol=2e-3), (
         float(jnp.max(jnp.abs(logits[:, -1] - last[:, -1]))))
+
+
+def test_latent_decode_is_refused(built):
+    """Decode and paged decode of latent attention raise, naming the
+    latent cache they lack, instead of running a wrong cache."""
+    cfg, model, params = built["moonlight-16b-a3b"]
+    assert cfg.kv_lora_rank
+    with pytest.raises(NotImplementedError, match="latent KV cache"):
+        model.init_cache(B, 16)
+    with pytest.raises(NotImplementedError, match="latent KV cache"):
+        model.init_paged_cache(B, 16, page_size=4, num_pages=8)
+    with pytest.raises(NotImplementedError, match="latent KV cache"):
+        model.decode(params, {}, {"tokens": jnp.ones((B, 1), jnp.int32)})
+    with pytest.raises(NotImplementedError, match="latent KV cache"):
+        model.decode_paged(params, {}, {"tokens": jnp.ones((B, 1),
+                                                           jnp.int32)})

@@ -146,13 +146,15 @@ def test_full_width_decode_step_fits_one_v5e(one_chip, cache_impl):
 # Attention dispatch and the full-width fsdp train step on four chips
 # ---------------------------------------------------------------------------
 
-def _attend_jaxpr(mesh, B=4, S=2048, D=128, kv_len=None, impl="auto"):
+def _attend_jaxpr(mesh, B=4, S=2048, D=128, kv_len=None, impl="auto",
+                  Dv=None):
     cfg = get_config("starcoder2-3b").replace(attn_impl=impl)
     q = jax.ShapeDtypeStruct((B, S, cfg.num_heads, D), BF16)
     kv = jax.ShapeDtypeStruct((B, S, cfg.num_kv_heads, D), BF16)
+    v = jax.ShapeDtypeStruct((B, S, cfg.num_kv_heads, Dv or D), BF16)
     with use_mesh(mesh, "fsdp"):
         return str(jax.make_jaxpr(lambda q, k, v: A.attend(
-            q, k, v, cfg, kv_len=kv_len))(q, kv, kv))
+            q, k, v, cfg, kv_len=kv_len))(q, kv, v))
 
 
 def test_auto_takes_the_kernel_per_shard_on_v5e(four_chips):
@@ -165,6 +167,8 @@ def test_auto_takes_the_kernel_per_shard_on_v5e(four_chips):
     dict(D=64),                      # head_dim off the lanes
     dict(B=2),                       # batch does not divide the data axes
     dict(kv_len=jnp.int32(5)),       # masked kv lengths
+    dict(Dv=64),                     # v's head dim is not q's
+    dict(D=192, Dv=128),             # latent attention's q/k and v heads
 ])
 def test_auto_falls_back_to_the_scan(four_chips, case):
     text = _attend_jaxpr(four_chips, **case)
@@ -239,3 +243,57 @@ def test_kernels_carry_the_attn_region_and_their_pass(fsdp_steps):
     bwd = [n for n in names if "transpose(" in n and n not in recompute]
     fwd = [n for n in names if "transpose(" not in n]
     assert len(fwd) == len(recompute) == 1 and len(bwd) == 2, names
+
+
+# ---------------------------------------------------------------------------
+# Moonlight-16B-A3B: one chip's share of an 8-way expert-parallel layer
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def moonlight_step(topo):
+    """The benchmark cell's step at published widths: the dense layer and
+    5 expert layers holding 8 of 64 experts, a 20,480-row vocabulary
+    slice, 4 x 4,096 tokens, full remat, f32 momentum state, fsdp on one
+    chip."""
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    tcfg = TrainConfig(remat="full", layout="fsdp")
+    rep = NamedSharding(mesh, P())
+    model = build_model(get_config("moonlight-16b-a3b").replace(
+        num_layers=6, vocab_size=20480, experts_held=8))
+    shard = param_shardings(model.abstract_params(), model.cfg, mesh,
+                            layout="fsdp")
+    state_shard = TrainState(params=shard, opt={"mu": shard}, step=rep)
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(lambda k: init_state(model, tcfg, k),
+                       jax.random.key(0)), state_shard)
+    tokens = jax.ShapeDtypeStruct((4, 4096), I32)
+    bshard = batch_shardings({"tokens": tokens, "labels": tokens}, mesh,
+                             "fsdp")
+    batch = {k: jax.ShapeDtypeStruct(tokens.shape, I32, sharding=sh)
+             for k, sh in bshard.items()}
+    step = jax.jit(make_train_step(model, tcfg, param_shardings=shard),
+                   in_shardings=(state_shard, bshard, rep),
+                   out_shardings=(state_shard, None), donate_argnums=(0,))
+    with use_mesh(mesh, "fsdp"):
+        return step.lower(state, batch, _on(rep, jax.ShapeDtypeStruct(
+            (), F32))).compile()
+
+
+def test_moonlight_step_fits_one_v5e(moonlight_step):
+    assert _hbm(moonlight_step) < V5E_HBM_BYTES, _hbm(moonlight_step)
+
+
+def test_moonlight_step_runs_grouped_matmuls_in_the_expert_regions(
+        moonlight_step):
+    """The held experts' grouped matmuls compile for v5e (``ragged_dot``)
+    and, with the routing and sorting, carry the ``router`` and
+    ``experts`` regions inside ``mlp`` that ``region_share`` reads; the
+    latent attention keeps the XLA scan (no kernel in the step)."""
+    text = moonlight_step.as_text()
+    assert "ragged-dot" in text and "flash" not in text
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for inner in ("router", "experts"):
+        assert any(f"/mlp/{inner}/" in n for n in names), inner
+    assert any("/attn/kernel.attention.xla_scan/" in n for n in names)
