@@ -40,8 +40,9 @@ from repro.models import ffn as F
 from repro.models import layers as L
 from repro.models import rwkv as R
 from repro.models import ssm as M
-from repro.obs.profiling import region
-from repro.sharding import shard_act
+from repro.obs.profiling import annotate_span, region
+from repro.sharding import (current_layout, gather_layer, layer_gathers,
+                            shard_act)
 
 PyTree = Any
 
@@ -218,11 +219,112 @@ def num_shared_invocations(cfg: ModelConfig) -> int:
 # Forward (train / prefill): params are RAW (unboxed) dicts
 # ---------------------------------------------------------------------------
 
-def _scan(body, x, xs, cfg: ModelConfig, remat: bool = True):
-    if remat:
-        body = jax.checkpoint(body, prevent_cse=False)
+def _scan(body, x, stack, cfg: ModelConfig, remat: bool = True, *, init,
+          extra=None):
+    """Run ``body(carry, xs) -> (carry, None)`` over a trunk's stacked
+    layer weights ``stack`` (made by ``stack_layers(init, ...)``); ``xs``
+    is a layer's weights, or ``(weights, extra_i)`` where per-layer
+    ``extra`` inputs are given. With ``remat`` the backward pass
+    recomputes each layer from its input, and where the layout also
+    shards the stacked weights over the mesh (``sharding.layer_gathers``)
+    the forward pass gathers each layer's weights one layer ahead
+    (``_prefetch_scan``)."""
+    plan = None
+    if remat and current_layout() == "fsdp":
+        layer = jax.eval_shape(lambda k: init(L.KeyGen(k), cfg),
+                               jax.ShapeDtypeStruct((2,), jnp.uint32))
+        plan = layer_gathers(layer, stack, cfg)
     with region("layers"):
-        return jax.lax.scan(body, x, xs)
+        if plan is not None:
+            return _prefetch_scan(body, x, stack, extra, plan), None
+        if remat:
+            body = jax.checkpoint(body, prevent_cse=False)
+        return jax.lax.scan(body, x, stack if extra is None
+                            else (stack, extra))
+
+
+# The named scope of the forward scan's prefetched weight gathers. It is
+# not a region (obs/profiling.REGIONS), so no region metric changes
+# meaning: its ops count in ``layer_loop``, or as collectives by name.
+PREFETCH_SCOPE = "fsdp.prefetch"
+
+
+def _prefetch_scan(body, x, stack, extra, plan):
+    """``_scan``'s forward pass with each layer's weights gathered one
+    layer ahead; its backward pass is ``_scan``'s.
+
+    The forward scan carries layer l's gathered weights and gathers layer
+    l+1's (``sharding.gather_layer``) while layer l computes, so each
+    gather can run beside the previous layer's compute rather than before
+    its own layer's first matmul. The backward pass (a ``custom_vjp``)
+    runs the layers in reverse as the checkpointed ``_scan`` does: it
+    recomputes layer l from its saved input and the stacked weights as
+    stored, and takes the layer's vjp, leaving its collectives and the
+    weight gradients' reduction where GSPMD puts them. Only each layer's
+    input is saved, as ``jax.checkpoint`` saves it; a plain scan whose
+    carry held gathered weights would save every layer's. Values that
+    ``body`` closes over and that are differentiated (the decoder's
+    cross-attention reads the encoder output) become arguments of the
+    ``custom_vjp`` so that their gradients flow.
+    """
+    n = jax.tree.leaves(stack)[0].shape[0]
+    steps = jnp.arange(n, dtype=jnp.int32)
+
+    def layer(c, w, i):
+        xs = w if extra is None else (
+            w, jax.tree.map(lambda e: e[i], extra))
+        return body(c, xs)[0]
+
+    layer, consts = jax.closure_convert(
+        layer, x, jax.tree.map(lambda g, a: jax.ShapeDtypeStruct(
+            a.shape[1:], g.dtype), plan, stack),
+        jax.ShapeDtypeStruct((), jnp.int32))
+
+    def gather(stack, i):
+        with annotate_span(PREFETCH_SCOPE):
+            return gather_layer(plan, jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                stack))
+
+    def forward(x, stack, consts):
+        def step(carry, i):
+            c, w = carry
+            # the last layer gathers itself again: one idle gather a pass
+            # keeps the loop body the same for every layer
+            w_next = gather(stack, jnp.minimum(i + 1, n - 1))
+            return (layer(c, w, i, *consts), w_next), c
+
+        # two layers a loop body: the gather for layer l+2 can then fill
+        # the buffer layer l's weights leave, where one layer a body
+        # copies every gathered weight into the loop's carry
+        (c, _), cs = jax.lax.scan(step, (x, gather(stack, 0)), steps,
+                                  unroll=2)
+        return c, (cs, stack, consts)
+
+    def backward(res, ct):
+        cs, stack, consts = res
+
+        def step(carry, xs):
+            ct_c, ct_k = carry
+            c, lp, i = xs
+
+            def recompute(c, lp, k):
+                w = jax.tree.map(lambda g, a: a.astype(g.dtype), plan, lp)
+                return layer(c, w, i, *k)
+
+            _, vjp = jax.vjp(jax.checkpoint(recompute, prevent_cse=False),
+                             c, lp, consts)
+            ct_c, ct_lp, dk = vjp(ct_c)
+            return (ct_c, jax.tree.map(jnp.add, ct_k, dk)), ct_lp
+
+        zeros = jax.tree.map(jnp.zeros_like, consts)
+        (ct_x, ct_k), ct_stack = jax.lax.scan(
+            step, (ct, zeros), (cs, stack, steps), reverse=True)
+        return ct_x, ct_stack, ct_k
+
+    run = jax.custom_vjp(lambda x, stack, consts: forward(x, stack, consts)[0])
+    run.defvjp(forward, backward)
+    return run(x, stack, consts)
 
 
 def _dense_trunk(params, cfg: ModelConfig, x, positions, mrope_positions=None,
@@ -237,7 +339,8 @@ def _dense_trunk(params, cfg: ModelConfig, x, positions, mrope_positions=None,
         h = _mlp_block(lp, h, cfg)
         return h, None
 
-    x, _ = _scan(body, x, (params["layers"], windows), cfg, remat)
+    x, _ = _scan(body, x, params["layers"], cfg, remat,
+                 init=_init_dense_layer, extra=windows)
     return x
 
 
@@ -247,7 +350,8 @@ def _moe_trunk(params, cfg: ModelConfig, x, positions, remat=True):
             h = _attn_block(lp, h, cfg, window=jnp.int32(0), positions=positions)
             h = _mlp_block(lp, h, cfg)
             return h, None
-        x, _ = _scan(dbody, x, params["dense_layers"], cfg, remat)
+        x, _ = _scan(dbody, x, params["dense_layers"], cfg, remat,
+                     init=_init_moe_dense_layer)
 
     def body(carry, lp):
         h, aux = carry
@@ -256,7 +360,7 @@ def _moe_trunk(params, cfg: ModelConfig, x, positions, remat=True):
         return (h, F.merge_aux(aux, a)), None
 
     (x, aux), _ = _scan(body, (x, F.aux_zeros(cfg)), params["layers"], cfg,
-                        remat)
+                        remat, init=_init_moe_layer)
     return x, F.layers_aux(aux, params["layers"]["ln1"]["gamma"].shape[0])
 
 
@@ -282,7 +386,8 @@ def _hybrid_trunk(params, cfg: ModelConfig, x, positions, remat=True):
     with region("layers"):
         x, _ = jax.lax.scan(body, x, params["blocks"])
     if "tail" in params:
-        x, _ = _scan(mamba_body, x, params["tail"], cfg, remat)
+        x, _ = _scan(mamba_body, x, params["tail"], cfg, remat,
+                     init=_init_mamba_layer)
     return x
 
 
@@ -300,7 +405,8 @@ def _rwkv_trunk(params, cfg: ModelConfig, x, remat=True):
         out, _ = R.apply_cmix(lp["cmix"], hn, cfg, zeros_tok)
         return h + out, None
 
-    x, _ = _scan(body, x, params["layers"], cfg, remat)
+    x, _ = _scan(body, x, params["layers"], cfg, remat,
+                 init=_init_rwkv_layer)
     return x
 
 
@@ -322,7 +428,8 @@ def _encdec_trunk(params, cfg: ModelConfig, enc_x, dec_x, positions, remat=True)
         h = _mlp_block(lp, h, cfg)
         return h, None
 
-    x, _ = _scan(body, dec_x, params["layers"], cfg, remat)
+    x, _ = _scan(body, dec_x, params["layers"], cfg, remat,
+                 init=_init_cross_layer)
     return x
 
 

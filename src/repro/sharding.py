@@ -19,10 +19,12 @@ device run the identical model code.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import threading
-from typing import Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import MeshConfig, ModelConfig
@@ -187,6 +189,58 @@ def param_shardings(params, cfg: ModelConfig, mesh: Mesh, fsdp: bool = True,
                           layout=layout)
         return NamedSharding(mesh, spec)
     return jax.tree.map(one, params, is_leaf=L.is_boxed)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerGather:
+    """How a layer scan hands one leaf of a stacked trunk to a layer under
+    ``fsdp``: cast to ``dtype``, and gathered to ``whole`` (replicated)
+    unless ``whole`` is None."""
+    dtype: Any
+    whole: Optional[NamedSharding]
+
+
+def layer_gathers(layer, stack, cfg: ModelConfig):
+    """The gather plan of a scan over stacked layer weights, or None where
+    the scan should hand each layer its weights as stored.
+
+    ``layer`` is one layer's Boxed tree (abstract values, logical axes
+    without ``layers``) and ``stack`` the stacked weights it describes.
+    A plan exists only under ``use_mesh`` with layout ``fsdp``, on a mesh
+    of more than one device, where :func:`param_spec` shards some stacked
+    leaf: then each leaf maps to a :class:`LayerGather`. A sharded leaf is
+    gathered, except an expert leaf: gathering every expert to every
+    device would undo expert parallelism. Leaves the model casts
+    (``Boxed.cast``) move in ``cfg.dtype``, the dtype they are used in.
+    """
+    from repro.models import layers as L  # deferred: avoids import cycle
+
+    mesh, layout = current_mesh(), current_layout()
+    if mesh is None or mesh.size == 1 or layout != "fsdp":
+        return None
+
+    def one(b: L.Boxed, a: jax.Array) -> LayerGather:
+        spec = param_spec(("layers",) + b.axes, cfg, mesh, a.shape,
+                          layout=layout)
+        gather = "experts" not in b.axes and any(e is not None for e in spec)
+        return LayerGather(jnp.dtype(cfg.dtype) if b.cast else a.dtype,
+                           NamedSharding(mesh, P()) if gather else None)
+
+    plan = jax.tree.map(one, layer, stack, is_leaf=L.is_boxed)
+    if all(g.whole is None for g in jax.tree.leaves(plan)):
+        return None
+    return plan
+
+
+def gather_layer(plan, lp):
+    """One layer's weights (a slice of the stack) as the layer uses them:
+    each leaf cast to its plan's dtype, the gathered leaves replicated."""
+    def one(g: LayerGather, a: jax.Array) -> jax.Array:
+        a = a.astype(g.dtype)
+        if g.whole is None:
+            return a
+        return jax.lax.with_sharding_constraint(a, g.whole)
+    return jax.tree.map(one, plan, lp)
 
 
 def opt_state_spec(axes: Sequence[Optional[str]], cfg: ModelConfig,

@@ -210,7 +210,32 @@ def fsdp_steps(four_chips):
 
 
 def _all_gathers(text):
-    return len(re.findall(r"= \S+ all-gather(?:-start)?\(", text))
+    """The all-gathers a compiled program issues: each ``all-gather`` op
+    outside fused computations, and each asynchronous collective start
+    whose computation gathers. The TPU compiler copies an asynchronous
+    gather into every compute fusion it overlaps, so counting the text's
+    ``all-gather`` ops would count its schedule too."""
+    comps, fused, cur = {}, set(), None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            cur = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[cur.lstrip("%")] = []
+            continue
+        if cur is not None:
+            comps[cur.lstrip("%")].append(line)
+        fused.update(re.findall(r"calls=%?([\w.\-]+)", line))
+    gathers = re.compile(r" all-gather(?:-start)?\(")
+    n = 0
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            n += bool(gathers.search(line))
+            start = re.search(r"= .* fusion\(.*calls=%?([\w.\-]+)", line)
+            if start and "async-collective-start" in line.split("=")[0]:
+                n += any(gathers.search(x)
+                         for x in comps.get(start.group(1), ()))
+    return n
 
 
 def _hbm(compiled):
@@ -230,10 +255,27 @@ def test_fsdp_step_with_the_kernel_fits_one_v5e(fsdp_steps):
     assert auto < V5E_HBM_BYTES and auto <= xla, (auto, xla)
 
 
+def test_fsdp_forward_gathers_the_next_layer_asynchronously(fsdp_steps):
+    """The forward scan gathers the next layer's weights under
+    ``fsdp.prefetch`` as asynchronous collectives beside this layer's
+    compute (at least q, o and both MLP weights), and moves no
+    activations: its MLP runs on whole weights, not tensor-parallel."""
+    text = fsdp_steps["auto"].as_text()
+    fwd = "/jvp(layers)/while/body/"
+    done = re.findall(r'async-collective-done[\w.]* = [^\n]*op_name="([^"]*)"',
+                      text)
+    assert sum(fwd in n and "/fsdp.prefetch/" in n for n in done) >= 4, done
+    acts = re.findall(
+        r'= bf16\[4,2048,3072\][^\n]* all-gather\([^\n]*op_name="([^"]*)"',
+        text)
+    assert not [n for n in acts if fwd in n], acts
+
+
 def test_kernels_carry_the_attn_region_and_their_pass(fsdp_steps):
     """``region_share.attn`` and ``pass_share.*`` read the kernels' op_name:
     the forward, the recomputed forward and the backward each sit under
-    ``attn`` and say which pass they are."""
+    ``attn`` and say which pass they are. The prefetching forward scan
+    runs two layers a loop body, so its kernel appears twice."""
     names = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in fsdp_steps["auto"].as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -242,7 +284,7 @@ def test_kernels_carry_the_attn_region_and_their_pass(fsdp_steps):
     recompute = [n for n in names if "rematted_computation" in n]
     bwd = [n for n in names if "transpose(" in n and n not in recompute]
     fwd = [n for n in names if "transpose(" not in n]
-    assert len(fwd) == len(recompute) == 1 and len(bwd) == 2, names
+    assert len(fwd) == 2 and len(recompute) == 1 and len(bwd) == 2, names
 
 
 # ---------------------------------------------------------------------------
