@@ -1,5 +1,6 @@
 """End-to-end transient-cluster training — the paper's scenario on the
-elastic runtime (this is the ≥100-step end-to-end driver).
+elastic runtime (a ≥100-step end-to-end run). Membership is
+data: the runtime feeds the one train step row weights and an LR scale.
 
 A 4-slot sparse-mapping cluster trains a ~25M-param reduced starcoder2
 for 300 steps while the cluster lives through the paper's full event

@@ -322,14 +322,16 @@ class TrainConfig:
     optimizer: OptimizerConfig = field(default_factory=OptimizerConfig)
     schedule: ScheduleConfig = field(default_factory=ScheduleConfig)
     microbatches: int = 1          # gradient accumulation factor
-    remat: str = "full"            # "none" | "full" | "selective"
-    zero1: bool = True             # shard optimizer state over data axis
-    layout: str = "tp"             # "tp" (megatron, baseline) | "fsdp"
+    remat: str = "full"            # "none" | "full"
+    layout: str = "tp"             # one of sharding.LAYOUTS
     grad_dtype: str = "float32"    # "bfloat16" halves grad-reduce wire bytes
-    compression: str = "none"      # "none" | "topk" | "ternary" (pod axis)
-    compression_ratio: float = 0.01
     checkpoint_every: int = 1000
     seed: int = 0
+
+    def __post_init__(self):
+        if self.remat not in ("none", "full"):
+            raise ValueError(f"remat must be 'none' or 'full', not "
+                             f"{self.remat!r}")
 
 
 @dataclass(frozen=True)

@@ -5,7 +5,8 @@ Modules
 transient   lifetime distributions + server state (Fig 3, §II-B)
 pricing     Table II price book, per-second billing
 cluster     sparse mapping: slots / active set / shard ownership (§III-F)
-elastic     masked + remesh elastic execution, adaptive LR (C5/C6)
+elastic     membership as data: slot batches with row weights, adaptive
+            LR, through the one train step and loop (C5/C6)
 staleness   AsyncPSSimulator: exact async-PS semantics in JAX (C4)
 checkpoint  master-less replicated checkpointing + fast-save (C2)
 cost        analytic cost model + budget planner (C1, §III-C)
@@ -19,8 +20,7 @@ policy      online transient-aware provisioning policies + trace-replay
 from repro.core.cluster import SparseCluster, SlotState  # noqa: F401
 from repro.core.checkpoint import CheckpointManager  # noqa: F401
 from repro.core.elastic import (ElasticRuntime, RevocationEvent,  # noqa: F401
-                                make_hetero_train_step,
-                                make_masked_train_step, slot_batch)
+                                lr_scale, slot_batch, slot_counts)
 from repro.core.staleness import AsyncPSSimulator, AsyncWorker  # noqa: F401
 from repro.core.simulator import (ClusterSpec, WorkerSpec,  # noqa: F401
                                   simulate_many, simulate_run)

@@ -8,8 +8,8 @@ up front; this module closes the loop — policies observe the market (a
 ``Trace`` via its ``ReplayContext``) at decision epochs and re-plan the
 cluster, driving the same join/revoke flow the sparse-mapping runtime
 executes (``cluster.py``/``elastic.py``: joins pay ``JOIN_OVERHEAD_S``,
-revoked slots refill at the next epoch, membership changes are the
-masked/remesh path, so ``master_failover`` semantics apply).
+revoked slots refill at the next epoch, membership changes are runtime
+data to the one train step, so ``master_failover`` semantics apply).
 
 Policies
 --------

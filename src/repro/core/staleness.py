@@ -38,6 +38,7 @@ from repro.config import OptimizerConfig, ScheduleConfig
 from repro.core import pricing
 from repro.optim import make_optimizer, make_schedule
 from repro.optim.optimizers import clip_by_global_norm
+from repro.train.step import apply_update
 
 PyTree = Any
 
@@ -104,10 +105,7 @@ class AsyncPSSimulator:
             grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
             if clip and clip > 0:
                 grads, _ = clip_by_global_norm(grads, clip)
-            updates, new_opt = self.opt.update(grads, opt_state, ps_params, lr)
-            new_params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
-                                      ps_params, updates)
-            return new_params, new_opt
+            return apply_update(self.opt, grads, opt_state, ps_params, lr)
 
         self._push = jax.jit(push)
         self._loss = jax.jit(loss_fn)

@@ -19,7 +19,8 @@ realized membership timeline as ``SlotEvent``s.
 workload (64K steps) to a reduced training run and fed as
 warn/revoke/join events into
 
-- ``ElasticRuntime`` (masked mode): real JAX training of a reduced
+- ``ElasticRuntime`` (masked or hetero membership, through the one
+  train step and loop): real JAX training of a reduced
   config, eval accuracy measured on held-out data (the planted
   ``Cifar10Like`` task for the resnet family, next-token accuracy for
   LMs), revocation warnings triggering fast checkpoint saves;

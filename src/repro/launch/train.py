@@ -6,10 +6,10 @@ in: sharded deterministic data pipeline, masked elastic membership
 revocation trace (either a file of events or Monte-Carlo lifetimes drawn
 from the paper-calibrated distributions).
 
-On a real pod deployment the same Trainer/ElasticRuntime drive jit-ted
-SPMD steps on the production mesh (see launch/dryrun.py for the lowering);
-here the mesh is the host CPU and reduced configs make the loop runnable
-in seconds — the orchestration code paths are identical.
+``--elastic`` runs ``ElasticRuntime``, otherwise the static ``Trainer``;
+both run the one train step (``make_train_step``) through the same
+per-step code (``Trainer.run_step``). Here the mesh is the host CPU and
+reduced configs make the loop runnable in seconds.
 """
 from __future__ import annotations
 

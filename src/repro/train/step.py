@@ -1,11 +1,14 @@
 """train_step / serve_step factories.
 
-``make_train_step`` builds the jittable SPMD step: loss -> grad (with remat
-inside the model's scan-over-layers) -> clip -> LR schedule x adaptive
-worker scale -> optimizer update. Microbatching accumulates gradients with
-``lax.scan`` so the activation peak is one microbatch while collectives
-amortize over the full batch. The adaptive-LR multiplier (paper C6) enters
-as a *runtime scalar* so elastic membership changes never recompile.
+``make_train_step`` builds the jittable SPMD step, the only train step:
+loss -> grad (with remat inside the model's scan-over-layers) -> clip ->
+LR schedule x adaptive worker scale -> optimizer update. Microbatching
+accumulates gradients with ``lax.scan`` so the activation peak is one
+microbatch while collectives amortize over the full batch. Elastic
+membership is data: the adaptive-LR multiplier (paper C6) enters as a
+*runtime scalar*, and an optional ``row_weight`` leaf of the batch weighs
+each row in the loss mean (0 for an empty slot's rows), so membership
+changes never recompile.
 
 Cross-entropy uses the one-hot/elementwise form: with logits sharded
 (batch over 'data', vocab over 'model'), the one-hot product keeps every
@@ -15,7 +18,6 @@ small all-reduce instead of re-gathering the (B, S, V) logits.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -77,18 +79,29 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
     return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
 
 
+def _loss_weights(cfg: ModelConfig, batch: Dict[str, jax.Array],
+                  row_w: Optional[jax.Array]) -> Optional[jax.Array]:
+    """The cross-entropy's weights: per position (the VLM prefix mask)
+    times the optional per-row ``row_w``; None for an unweighted resnet."""
+    if cfg.family == "resnet":
+        return row_w
+    w = _token_weights(cfg, batch, batch["labels"].shape[1])
+    return w if row_w is None else w * row_w[:, None]
+
+
 def loss_fn(model: Model, params: PyTree, batch: Dict[str, jax.Array],
             tcfg: TrainConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The step's loss. ``batch`` may carry ``row_weight`` (B,) float32:
+    each row's weight in the loss mean; without it every row weighs 1."""
     cfg = model.cfg
+    row_w = batch.get("row_weight")
+    if row_w is not None:
+        batch = {k: v for k, v in batch.items() if k != "row_weight"}
     remat = tcfg.remat != "none"
     logits, aux = model.apply(params, batch, remat=remat)
     with region("head"):
-        if cfg.family == "resnet":
-            loss = cross_entropy(logits, batch["labels"])
-        else:
-            S = logits.shape[1]
-            w = _token_weights(cfg, batch, S)
-            loss = cross_entropy(logits, batch["labels"], w)
+        loss = cross_entropy(logits, batch["labels"],
+                             _loss_weights(cfg, batch, row_w))
     total = loss + cfg.router_aux_coef * aux["balance"]
     return total, aux_metrics(loss, aux)
 
@@ -105,6 +118,15 @@ def aux_metrics(loss: jax.Array, aux: Dict[str, jax.Array]
 # ---------------------------------------------------------------------------
 # train_step
 # ---------------------------------------------------------------------------
+
+def apply_update(opt, grads: PyTree, opt_state: PyTree, params: PyTree,
+                 lr: jax.Array) -> Tuple[PyTree, PyTree]:
+    """One optimizer update of ``params`` by f32 ``grads`` at ``lr``:
+    ``(params, opt_state)``, each leaf kept in its dtype."""
+    updates, new_opt = opt.update(grads, opt_state, params, lr)
+    return jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
+                        params, updates), new_opt
+
 
 def make_train_step(model: Model, tcfg: TrainConfig, param_shardings=None,
                     zero1_mask=None
@@ -134,6 +156,11 @@ def make_train_step(model: Model, tcfg: TrainConfig, param_shardings=None,
             lambda s, m: (NamedSharding(s.mesh, PartitionSpec())
                           if m else s),
             param_shardings, mask)
+
+    def loss_norm(batch):
+        """The loss's normalizer for a batch with row weights."""
+        w = _loss_weights(model.cfg, batch, batch["row_weight"])
+        return jnp.maximum(jnp.sum(w), 1.0)
 
     def grads_of(params, batch):
         compute_dt = (jnp.bfloat16 if tcfg.grad_dtype == "bfloat16"
@@ -165,9 +192,18 @@ def make_train_step(model: Model, tcfg: TrainConfig, param_shardings=None,
                    ) -> Tuple[TrainState, Dict[str, jax.Array]]:
         k = tcfg.microbatches
         if k > 1:
+            norm = loss_norm(batch) if "row_weight" in batch else None
+
             def mb(carry, mbatch):
                 g_acc, m_acc = carry
                 g, m = grads_of(state.params, mbatch)
+                if norm is not None:
+                    # a microbatch's loss is its own weighted mean: weigh it
+                    # by its share of the batch's weights (times k, as the
+                    # sum below and mean_aux divide by k)
+                    w = k * loss_norm(mbatch) / norm
+                    g = jax.tree.map(lambda b: (b * w).astype(b.dtype), g)
+                    m = dict(m, loss=m["loss"] * w)
                 g_acc = jax.tree.map(lambda a, b: a + b / k, g_acc, g)
                 return (g_acc, merge_aux(m_acc, m)), None
 
@@ -190,9 +226,8 @@ def make_train_step(model: Model, tcfg: TrainConfig, param_shardings=None,
                 gnorm = global_norm(grads)
 
             lr = base_lr * sched(state.step) * lr_scale
-            updates, new_opt = opt.update(grads, state.opt, state.params, lr)
-            new_params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
-                                      state.params, updates)
+            new_params, new_opt = apply_update(opt, grads, state.opt,
+                                               state.params, lr)
         new_state = TrainState(params=new_params, opt=new_opt,
                                step=state.step + 1)
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)

@@ -88,3 +88,5 @@ def test_remat_flag_changes_memory_model():
     without = analytic.step_hbm_bytes(
         model, cfg, SHAPES["train_4k"], mesh, tcfg=TrainConfig(remat="none"))
     assert without.activations < with_remat.activations
+    with pytest.raises(ValueError, match="remat"):
+        TrainConfig(remat="selective")           # "none" and "full" only

@@ -1,29 +1,11 @@
-"""RemeshCache template reuse + MoE serving engine coverage."""
+"""Serving engine coverage for the MoE and hybrid families."""
 import jax
 import numpy as np
-import pytest
 
 from repro.config import get_config
-from repro.core.elastic import RemeshCache
 from repro.models import layers as L
 from repro.models.builder import build_model
 from repro.serving import Request, ServeEngine
-
-
-def test_remesh_cache_compiles_once_per_size():
-    calls = []
-
-    def build(n_active):
-        calls.append(n_active)
-        return lambda x: x * n_active
-
-    cache = RemeshCache(build=build)
-    seq = [4, 3, 4, 2, 3, 4, 2]          # revocations and rejoins
-    for n in seq:
-        fn = cache.step_for(n)
-        assert fn(1) == n
-    assert cache.compile_count == 3       # {4, 3, 2} — repeats are hits
-    assert calls == [4, 3, 2]
 
 
 def test_serving_moe_arch():
